@@ -35,7 +35,7 @@ import numpy as np
 
 from repro.core.bns import BNSTrainConfig, TrainResult, psnr
 from repro.core.ns_solver import NSParams
-from repro.core.parametrization import VelocityField
+from repro.core.parametrization import VelocityField, as_partial
 from repro.optim import adam_init, adam_update, cosine_annealing, poly_decay
 
 Array = jax.Array
@@ -253,8 +253,8 @@ def train_anytime(field: VelocityField, budgets: Sequence[int], train_pairs,
     lr_fn = (poly_decay(cfg.lr, cfg.iterations) if cfg.lr_schedule == "poly"
              else cosine_annealing(cfg.lr, cfg.iterations))
 
-    def loss_fn(theta, x0b, x1b):
-        outs = anytime_sample(theta, budgets, field.fn, x0b)
+    def loss_fn(theta, x0b, x1b, u):
+        outs = anytime_sample(theta, budgets, u, x0b)
         total = 0.0
         for m in budgets:
             mse = jnp.mean((outs[m] - x1b) ** 2,
@@ -263,15 +263,19 @@ def train_anytime(field: VelocityField, budgets: Sequence[int], train_pairs,
                 jnp.mean(jnp.log(jnp.maximum(mse, 1e-20)))
         return total / wsum
 
+    # the field rides in as an argument: its backbone weights are program
+    # inputs, never constants baked into the step
+    u = as_partial(field.fn)
+
     @jax.jit
-    def step(theta, opt, it, x0b, x1b):
-        loss, grads = jax.value_and_grad(loss_fn)(theta, x0b, x1b)
+    def step(theta, opt, it, x0b, x1b, u):
+        loss, grads = jax.value_and_grad(loss_fn)(theta, x0b, x1b, u)
         theta, opt = adam_update(grads, opt, theta, lr_fn(it))
         return theta, opt, loss
 
     @jax.jit
-    def val_psnr(theta):
-        outs = anytime_sample(theta, budgets, field.fn, val_pairs[0])
+    def val_psnr(theta, u):
+        outs = anytime_sample(theta, budgets, u, val_pairs[0])
         return jnp.mean(jnp.stack(
             [jnp.mean(psnr(outs[m], val_pairs[1], cfg.max_val))
              for m in budgets]))
@@ -285,9 +289,9 @@ def train_anytime(field: VelocityField, budgets: Sequence[int], train_pairs,
         idx = (np.arange(num) if cfg.batch_size >= num
                else rng.choice(num, size=cfg.batch_size, replace=False))
         theta, opt, loss = step(theta, opt, jnp.asarray(it), x0_tr[idx],
-                                x1_tr[idx])
+                                x1_tr[idx], u)
         if (it + 1) % cfg.val_every == 0 or it == cfg.iterations - 1:
-            vp = float(val_psnr(theta))
+            vp = float(val_psnr(theta, u))
             history.append((it + 1, float(loss), vp))
             if vp > best[0]:
                 best = (vp, jax.tree.map(lambda x: x.copy(), theta))

@@ -8,7 +8,7 @@ Pipeline:
      Adam, tracking PSNR on a validation set and returning the best iterate.
 
 The same harness trains BST solvers (the prior-work baseline) by swapping the
-sampler closure.
+sampler.
 """
 from __future__ import annotations
 
@@ -19,10 +19,11 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.tree_util import Partial
 
 from repro.core import bst_solver, ns_solver, st_transform
 from repro.core.ns_solver import BNSParams, NSParams
-from repro.core.parametrization import VelocityField
+from repro.core.parametrization import VelocityField, as_partial
 from repro.core.rk45 import rk45_solve
 from repro.core.taxonomy import run_direct
 from repro.optim import adam_init, adam_update, cosine_annealing, poly_decay
@@ -47,14 +48,15 @@ def generate_pairs(
     source_std: float = 1.0,
 ) -> tuple[Array, Array]:
     """Draw x0 ~ N(0, source_std^2) and integrate to x(1) with RK45."""
-    solve = jax.jit(lambda x0: rk45_solve(field.fn, x0, rtol=rtol, atol=atol).x1)
+    solve = jax.jit(lambda u, x0: rk45_solve(u, x0, rtol=rtol, atol=atol).x1)
+    u = as_partial(field.fn)
     x0s, x1s = [], []
     for start in range(0, num, batch_size):
         b = min(batch_size, num - start)
         key, sub = jax.random.split(key)
         x0 = source_std * jax.random.normal(sub, (b,) + shape)
         x0s.append(x0)
-        x1s.append(solve(x0))
+        x1s.append(solve(u, x0))
     return jnp.concatenate(x0s), jnp.concatenate(x1s)
 
 
@@ -86,11 +88,14 @@ def solver_to_ns(
     return build_ns(name, nfe, field, sigma0=sigma0, grid=grid)
 
 
-def ns_sampler(field: VelocityField) -> Callable[[BNSParams, Array], Array]:
-    def sample(theta: BNSParams, x0: Array) -> Array:
-        return ns_solver.ns_sample(ns_solver.materialize(theta), field.fn, x0)
+def _ns_sample(u_fn, theta: BNSParams, x0: Array) -> Array:
+    return ns_solver.ns_sample(ns_solver.materialize(theta), u_fn, x0)
 
-    return sample
+
+def ns_sampler(field: VelocityField) -> Callable[[BNSParams, Array], Array]:
+    """``sample(theta, x0)`` as a ``Partial`` over the field's ``fn``, so a
+    jit taking the sampler as an argument gets the backbone as inputs."""
+    return Partial(_ns_sample, as_partial(field.fn))
 
 
 def bst_sampler(field: VelocityField, base: str = "euler"):
@@ -159,15 +164,19 @@ def train_solver(
     lr_fn = (poly_decay(cfg.lr, cfg.iterations) if cfg.lr_schedule == "poly"
              else cosine_annealing(cfg.lr, cfg.iterations))
 
+    # the sampler rides in as an argument: its backbone weights are program
+    # inputs, never constants baked into the step
+    sampler = as_partial(sampler)
+
     @jax.jit
-    def step(theta, opt, it, x0b, x1b):
+    def step(theta, opt, it, x0b, x1b, sampler):
         loss, grads = jax.value_and_grad(
             lambda th: _loss_fn(sampler, th, x0b, x1b))(theta)
         theta, opt = adam_update(grads, opt, theta, lr_fn(it))
         return theta, opt, loss
 
     @jax.jit
-    def val_psnr_fn(theta):
+    def val_psnr_fn(theta, sampler):
         return jnp.mean(psnr(sampler(theta, val_pairs[0]), val_pairs[1],
                              cfg.max_val))
 
@@ -183,9 +192,10 @@ def train_solver(
         # keep the order (no shuffling).
         idx = np.arange(num) if full_batch else \
             rng.choice(num, size=cfg.batch_size, replace=False)
-        theta, opt, loss = step(theta, opt, jnp.asarray(it), x0_tr[idx], x1_tr[idx])
+        theta, opt, loss = step(theta, opt, jnp.asarray(it), x0_tr[idx],
+                                x1_tr[idx], sampler)
         if (it + 1) % cfg.val_every == 0 or it == cfg.iterations - 1:
-            vp = float(val_psnr_fn(theta))
+            vp = float(val_psnr_fn(theta, sampler))
             history.append((it + 1, float(loss), vp))
             if vp > best[0]:
                 best = (vp, jax.tree.map(lambda x: x.copy(), theta))

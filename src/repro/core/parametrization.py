@@ -12,6 +12,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.tree_util import Partial
 
 from repro.core.schedulers import Scheduler
 
@@ -52,6 +53,14 @@ class VelocityField:
 
     def __call__(self, t: Array, x: Array) -> Array:
         return self.fn(t, x)
+
+
+def as_partial(fn: Callable) -> Partial:
+    """``fn`` as a pytree that a jit can take as an argument. A
+    ``Partial`` (e.g. the backbone's ``models.model.velocity_field``)
+    passes through, so its arrays reach the program as inputs; any other
+    callable becomes a leafless ``Partial`` and stays static."""
+    return fn if isinstance(fn, Partial) else Partial(fn)
 
 
 def as_velocity_field(

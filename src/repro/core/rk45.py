@@ -11,22 +11,25 @@ from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
-# Dormand-Prince Butcher tableau (DOPRI5).
-_C = jnp.array([0.0, 1 / 5, 3 / 10, 4 / 5, 8 / 9, 1.0, 1.0])
-_A = jnp.array([
+# Dormand-Prince Butcher tableau (DOPRI5). NumPy constants, so importing
+# this module never starts a JAX backend.
+_C = np.array([0.0, 1 / 5, 3 / 10, 4 / 5, 8 / 9, 1.0, 1.0], np.float32)
+_A = np.array([
     [0, 0, 0, 0, 0, 0],
     [1 / 5, 0, 0, 0, 0, 0],
     [3 / 40, 9 / 40, 0, 0, 0, 0],
     [44 / 45, -56 / 15, 32 / 9, 0, 0, 0],
     [19372 / 6561, -25360 / 2187, 64448 / 6561, -212 / 729, 0, 0],
     [9017 / 3168, -355 / 33, 46732 / 5247, 49 / 176, -5103 / 18656, 0],
-])
-_B5 = jnp.array([35 / 384, 0.0, 500 / 1113, 125 / 192, -2187 / 6784, 11 / 84, 0.0])
-_B4 = jnp.array([5179 / 57600, 0.0, 7571 / 16695, 393 / 640,
-                 -92097 / 339200, 187 / 2100, 1 / 40])
+], np.float32)
+_B5 = np.array([35 / 384, 0.0, 500 / 1113, 125 / 192, -2187 / 6784, 11 / 84, 0.0],
+               np.float32)
+_B4 = np.array([5179 / 57600, 0.0, 7571 / 16695, 393 / 640,
+                 -92097 / 339200, 187 / 2100, 1 / 40], np.float32)
 
 
 class RK45Result(NamedTuple):

@@ -34,11 +34,9 @@ def jax_initialized() -> bool:
     still take effect after it."""
     if "jax" not in sys.modules:
         return False
-    try:
-        from jax._src import xla_bridge
-    except Exception:                    # layout moved: assume the worst
-        return True
-    return bool(getattr(xla_bridge, "_backends", None))
+    from jax._src import xla_bridge
+
+    return bool(xla_bridge._backends)
 
 
 def emulate_hosts(n: int) -> int:
@@ -70,7 +68,7 @@ def host_meshes(n: int, axes: tuple = ("data", "model")):
     ``emulate_hosts`` (the footgun this module exists to defuse)."""
     import jax
     import numpy as np
-    from jax.sharding import Mesh
+    from jax.sharding import AxisType, Mesh
 
     if n < 1:
         raise ValueError(f"need at least 1 host, got {n}")
@@ -83,5 +81,5 @@ def host_meshes(n: int, axes: tuple = ("data", "model")):
     per = len(devices) // n
     shape = (per,) + (1,) * (len(axes) - 1)
     return [Mesh(np.asarray(devices[i * per:(i + 1) * per]).reshape(shape),
-                 axes)
+                 axes, axis_types=(AxisType.Auto,) * len(axes))
             for i in range(n)]

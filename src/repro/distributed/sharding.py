@@ -153,7 +153,7 @@ def state_specs(state_shape, cfg: ModelConfig, mesh, batch: int):
 
     Matches on leaf rank/shape within the known state NamedTuples:
       KVCache.k/v           (L, B, slots, KV, hd)
-      PagedKVCache.k_pages/v_pages  (L, num_pages, page_size, KV, hd)
+      PagedKVCache.k_pages/v_pages  (L, num_pages, KV, page_size, hd)
         — no batch axis (the pool is shared by every row); shard the KV
         heads on ``model`` when divisible, like the dense cache. The block
         table / per-row index stay replicated: every shard needs the full
@@ -177,10 +177,10 @@ def state_specs(state_shape, cfg: ModelConfig, mesh, batch: int):
         nd = len(leaf.shape)
         if nd == 0:
             return P()                                     # index scalar
-        if name in ("k_pages", "v_pages"):     # (L, pages, page_size, KV, hd)
-            kv = leaf.shape[3]
-            return P(None, None, None,
-                     "model" if kv % mp == 0 else None, None)
+        if name in ("k_pages", "v_pages"):     # (L, pages, KV, page_size, hd)
+            kv = leaf.shape[2]
+            return P(None, None, "model" if kv % mp == 0 else None,
+                     None, None)
         if name == "block_table":                          # (B, blocks) int32
             return P()
         if name in ("k", "v", "kv", "vv"):                 # (L/sites,B,slots,KV,hd)

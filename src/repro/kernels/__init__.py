@@ -12,5 +12,14 @@ oracle used by the allclose sweep tests).
                      the decay cube resident in VMEM (the dominant SSM-train
                      pathology)
 
-Validated with interpret=True on CPU; TPU is the target.
+Kernel entry points take ``interpret`` with no default. The ops wrappers
+decide it in ONE place, ``interpret_mode()``: compiled for the chip on a TPU
+backend, the Pallas interpreter everywhere else (CPU tests).
 """
+import jax
+
+
+def interpret_mode() -> bool:
+    """True unless the default backend is a TPU: Pallas TPU kernels run in
+    the interpreter off-chip."""
+    return jax.default_backend() != "tpu"

@@ -67,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 @functools.partial(jax.jit, static_argnames=("causal", "bq", "bk", "interpret"))
 def flash_attention(q: Array, k: Array, v: Array, *, causal: bool = True,
                     bq: int = 128, bk: int = 128,
-                    interpret: bool = True) -> Array:
+                    interpret: bool) -> Array:
     """q: (B, H, Lq, hd); k, v: (B, KV, Lk, hd); H % KV == 0. Returns q-shaped."""
     B, H, Lq, hd = q.shape
     KV, Lk = k.shape[1], k.shape[2]

@@ -1,7 +1,7 @@
 """Pallas TPU paged attention — decode-step attention over a paged KV cache.
 
 vLLM-style PagedAttention: K/V live in a shared pool of fixed-size pages
-(``k_pages``/``v_pages``: (num_pages, page_size, KV, hd)) and each sequence
+(``k_pages``/``v_pages``: (num_pages, KV, page_size, hd)) and each sequence
 owns a per-slot row of a BLOCK TABLE mapping its logical block index to a
 physical page id. One decode step attends each query row over its own pages
 only, so per-slot cache memory is the pages the sequence actually uses, not
@@ -20,6 +20,13 @@ predicated out with ``pl.when`` (the decode twin of the causal block skip).
 Rows that are shorter than the pool's widest resident sequence pay only
 their own pages: the skip guard reads ``lengths[b]`` from the prefetched
 scalars. ``interpret=True`` runs the same kernel off-TPU (CI).
+
+Each K/V block is one page of one KV head: its last two dims,
+``(page_size, hd)``, span the pool's last two dims, which the TPU lowering
+accepts for every page size and dtype (a block that is not a multiple of
+the (8, 128) tile must equal the array's dims). Pages that are a multiple
+of the dtype's sublane tile (8 rows of 32-bit, 16 of 16-bit) waste no
+padding in VMEM.
 """
 from __future__ import annotations
 
@@ -51,8 +58,8 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(i * ps < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale          # (G, hd)
-        k = k_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
-        v = v_ref[0, :, 0].astype(jnp.float32)               # (ps, hd)
+        k = k_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
+        v = v_ref[0, 0].astype(jnp.float32)                  # (ps, hd)
         s = q @ k.T                                          # (G, ps)
         kpos = i * ps + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(kpos < length, s, NEG_INF)
@@ -73,17 +80,17 @@ def _kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention(q: Array, k_pages: Array, v_pages: Array,
                     block_table: Array, lengths: Array, *,
-                    interpret: bool = True) -> Array:
+                    interpret: bool) -> Array:
     """One-token paged decode attention.
 
     q: (B, KV, G, hd) grouped query heads; k_pages/v_pages:
-    (num_pages, page_size, KV, hd) shared page pool; block_table: (B, nb)
+    (num_pages, KV, page_size, hd) shared page pool; block_table: (B, nb)
     int32 physical page ids per logical block; lengths: (B,) int32 valid
     positions per row (the current token already written). Returns
     (B, KV, G, hd).
     """
     B, KV, G, hd = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     nb = block_table.shape[1]
     scale = hd ** -0.5
     kernel = functools.partial(_kernel, ps=ps, nb=nb, scale=scale)
@@ -93,10 +100,10 @@ def paged_attention(q: Array, k_pages: Array, v_pages: Array,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, h, i, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, bt, ln: (bt[b, i], 0, h, 0)),
-            pl.BlockSpec((1, ps, 1, hd),
-                         lambda b, h, i, bt, ln: (bt[b, i], 0, h, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda b, h, i, bt, ln: (bt[b, i], h, 0, 0)),
+            pl.BlockSpec((1, 1, ps, hd),
+                         lambda b, h, i, bt, ln: (bt[b, i], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, i, bt, ln: (b, h, 0, 0)),

@@ -20,20 +20,28 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     return o.reshape(B, H, Lq, hd).astype(q.dtype)
 
 
+def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
+    """Row-major copy of each row's pages: pool (num_pages, KV, ps, hd) and
+    block_table (B, nb) -> (B, nb*ps, KV, hd), row b's logical positions."""
+    B, nb = block_table.shape
+    _, KV, ps, hd = pages.shape
+    rows = pages[block_table].transpose(0, 1, 3, 2, 4)      # (B, nb, ps, KV, hd)
+    return rows.reshape(B, nb * ps, KV, hd)
+
+
 def paged_attention_ref(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                         block_table: jax.Array,
                         lengths: jax.Array) -> jax.Array:
     """Dense-gather oracle for the paged decode kernel.
 
-    q: (B, KV, G, hd); k_pages/v_pages: (num_pages, page_size, KV, hd);
+    q: (B, KV, G, hd); k_pages/v_pages: (num_pages, KV, page_size, hd);
     block_table: (B, nb) int32; lengths: (B,) valid positions per row.
     """
     B, KV, G, hd = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     nb = block_table.shape[1]
-    # (B, nb, ps, KV, hd) -> (B, nb*ps, KV, hd): row b's logical positions
-    k = k_pages[block_table].reshape(B, nb * ps, KV, hd).astype(jnp.float32)
-    v = v_pages[block_table].reshape(B, nb * ps, KV, hd).astype(jnp.float32)
+    k = gather_pages(k_pages, block_table).astype(jnp.float32)
+    v = gather_pages(v_pages, block_table).astype(jnp.float32)
     qf = q.astype(jnp.float32) * hd ** -0.5
     s = jnp.einsum("bkgh,bskh->bkgs", qf, k)
     valid = jnp.arange(nb * ps)[None, :] < lengths[:, None]

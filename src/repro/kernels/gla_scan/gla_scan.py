@@ -66,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, ld_ref, o_ref, s_out_ref, s_scr, *,
                    static_argnames=("inclusive", "chunk", "interpret"))
 def gla_scan(q: Array, k: Array, v: Array, ld: Array, *,
              inclusive: bool = True, chunk: int = 64,
-             interpret: bool = True) -> tuple[Array, Array]:
+             interpret: bool) -> tuple[Array, Array]:
     """q, k, ld: (B, L, H, dk); v: (B, L, H, dv); L % chunk == 0.
 
     Returns (o: (B, L, H, dv), final state: (B, H, dk, dv))."""

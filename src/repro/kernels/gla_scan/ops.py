@@ -1,8 +1,7 @@
 """Jit'd wrapper: Pallas GLA scan on TPU, interpret elsewhere, jnp fallback."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.gla_scan.gla_scan import gla_scan
 from repro.models.linear_scan import gla_chunked
 
@@ -12,6 +11,6 @@ def gla(q, k, v, ld, *, inclusive: bool = True, chunk: int = 64,
     if not use_kernel:
         return gla_chunked(q, k, v, ld, inclusive=inclusive, chunk=chunk)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     return gla_scan(q, k, v, ld, inclusive=inclusive, chunk=chunk,
                     interpret=interpret)

@@ -35,7 +35,7 @@ def _kernel(coeff_ref, x0_ref, u_ref, o_ref, *, n: int):
 @functools.partial(jax.jit, static_argnames=("block_b", "block_d", "interpret"))
 def ns_update(x0: Array, u: Array, a: Array, w: Array, *,
               block_b: int = 8, block_d: int = 512,
-              interpret: bool = True) -> Array:
+              interpret: bool) -> Array:
     """x0: (B, D); u: (n, B, D); a: scalar; w: (n,). Returns (B, D).
 
     Rows of ``w`` beyond the current step must already be zero (the caller
@@ -61,7 +61,8 @@ def ns_update(x0: Array, u: Array, a: Array, w: Array, *,
     )(coeff, x0, u)
 
 
-def ns_update_nd(x0: Array, u: Array, a: Array, w: Array, **kw) -> Array:
+def ns_update_nd(x0: Array, u: Array, a: Array, w: Array, *,
+                 interpret: bool) -> Array:
     """Arbitrary trailing dims: x0 (B, ...), u (n, B, ...)."""
     shape = x0.shape
     x2 = x0.reshape(shape[0], -1)
@@ -73,10 +74,8 @@ def ns_update_nd(x0: Array, u: Array, a: Array, w: Array, **kw) -> Array:
         x2 = jnp.pad(x2, ((0, 0), (0, pad)))
         u2 = jnp.pad(u2, ((0, 0), (0, 0), (0, pad)))
     bd = 512 if (D + pad) % 512 == 0 else 128
-    bb = 1
-    for c in (8, 4, 2, 1):
-        if shape[0] % c == 0:
-            bb = c
-            break
-    out = ns_update(x2, u2, a, w, block_b=bb, block_d=bd, **kw)
+    # a batch block must tile the TPU's sublanes: a multiple of 8 rows, or
+    # the whole batch (any size is legal when the block spans the dim)
+    bb = 8 if shape[0] % 8 == 0 else shape[0]
+    out = ns_update(x2, u2, a, w, block_b=bb, block_d=bd, interpret=interpret)
     return out[:, :D].reshape(shape)

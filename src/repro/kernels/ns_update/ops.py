@@ -2,8 +2,7 @@
 elsewhere. ``make_update_fn`` plugs into ``ns_solver.ns_sample(update_fn=...)``."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import interpret_mode
 from repro.kernels.ns_update.ns_update import ns_update_nd
 from repro.kernels.ns_update.ref import ns_update_ref
 
@@ -13,7 +12,7 @@ def fused_ns_update(x0, u, a, w, *, use_kernel: bool = True,
     if not use_kernel:
         return ns_update_ref(x0, u, a, w)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     return ns_update_nd(x0, u, a, w, interpret=interpret)
 
 

@@ -4,10 +4,10 @@
 oriented environment before JAX initializes:
 
 * ``TUNED_XLA_FLAGS`` — the XLA GPU flags production serving stacks ship
-  with (triton softmax fusion + gemm autotuning, async collectives, the
-  latency-hiding scheduler, highest-priority async stream). Harmless
-  no-ops on CPU/TPU backends: XLA parses and ignores flags that do not
-  apply to the active backend.
+  with (triton gemm autotuning, the latency-hiding scheduler, highest-
+  priority async stream). No-ops on CPU/TPU backends, which parse and
+  ignore flags of another backend; a flag the installed XLA does not
+  know at all aborts the process, so the set holds only known flags.
 * tcmalloc — host-side allocator preload (``LD_PRELOAD``), applied only
   when one of the known shared-object paths exists on this machine. The
   large-alloc report threshold is raised so steady-state serving does not
@@ -25,9 +25,7 @@ import os
 import sys
 
 TUNED_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true",
     "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
     "--xla_gpu_enable_latency_hiding_scheduler=true",
     "--xla_gpu_enable_highest_priority_async_stream=true",
 )

@@ -76,9 +76,11 @@ import jax
 from repro.checkpoint import checkpointer
 from repro.configs import get_config
 from repro.core.bns import BNSTrainConfig
+from repro.core.parametrization import as_partial
 from repro.core.rk45 import rk45_solve
 from repro.core.schedulers import get_scheduler
 from repro.data.synthetic import DataConfig, SyntheticTokens
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.observability import (
     MetricsServer,
@@ -134,7 +136,7 @@ def _finish_telemetry(args, gw) -> None:
         print(f"trace: {n} events written to {args.trace_jsonl}")
 
 
-def _requested_spec(args) -> SolverSpec:
+def requested_spec(args) -> SolverSpec:
     """The solver the CLI asks for: anytime over --budgets, else fixed-NFE BNS."""
     if args.budgets:
         return SolverSpec(name="midpoint", mode="anytime",
@@ -143,17 +145,20 @@ def _requested_spec(args) -> SolverSpec:
                       cfg_scale=args.cfg_scale, mode="bns")
 
 
-def _distill_artifact(args, field, cfg, spec: SolverSpec) -> SolverArtifact:
+def distill_artifact(args, field, cfg, spec: SolverSpec) -> SolverArtifact:
     """Algorithm 2 on fresh RK45 pairs; returns the saved-and-reloaded artifact."""
     what = (f"anytime solver (budgets={spec.budgets})" if spec.budgets
             else f"BNS solver (NFE={spec.nfe})")
     print(f"distilling {what} ...")
-    solve = jax.jit(lambda x: rk45_solve(field.fn, x, rtol=1e-5, atol=1e-5).x1)
+    # the field rides in as an argument, so the backbone weights are inputs
+    # of the solve program, not constants baked into it
+    solve = jax.jit(lambda u, x: rk45_solve(u, x, rtol=1e-5, atol=1e-5).x1)
+    u = as_partial(field.fn)
     k_tr, k_val = jax.random.split(jax.random.PRNGKey(args.seed + 1))
     shape = (args.batch, args.seq, cfg.latent_dim)
     x0 = jax.random.normal(k_tr, shape)
     x0v = jax.random.normal(k_val, shape)  # held-out: no train/val leak
-    res = spec.distill(field, (x0, solve(x0)), (x0v, solve(x0v)),
+    res = spec.distill(field, (x0, solve(u, x0)), (x0v, solve(u, x0v)),
                        BNSTrainConfig(lr=1e-3, lr_schedule="cosine",
                                       iterations=args.bns_iters, val_every=100,
                                       batch_size=args.batch))
@@ -189,13 +194,22 @@ def _resolve_budget(artifact: SolverArtifact, nfe: int, strict: bool,
     return near
 
 
-def serve_flow(args) -> None:
-    cfg = get_config(args.arch, smoke=args.smoke)
-    sched = get_scheduler(args.scheduler)
-    params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
+def init_params(args, cfg):
+    """Backbone weights: random from --seed, then --ckpt when given. The
+    init is jitted — eager, it would hold a float32 copy of every layer at
+    once, which at published widths does not fit one chip."""
+    params = jax.jit(M.init_params, static_argnums=1)(
+        jax.random.PRNGKey(args.seed), cfg)
     if args.ckpt:
         params = checkpointer.restore(args.ckpt, params)
         print(f"restored params from {args.ckpt}")
+    return params
+
+
+def serve_flow(args) -> None:
+    cfg = get_config(args.arch, smoke=args.smoke)
+    sched = get_scheduler(args.scheduler)
+    params = init_params(args, cfg)
 
     data = SyntheticTokens(cfg, DataConfig(batch_size=args.batch,
                                            seq_len=args.seq, seed=args.seed))
@@ -206,7 +220,7 @@ def serve_flow(args) -> None:
                              os.path.dirname(args.solver_artifact)
                              if args.solver_artifact else None) if d]
     zoo = SolverZoo(capacity=args.zoo_capacity,
-                    distill_fn=lambda spec: _distill_artifact(args, field,
+                    distill_fn=lambda spec: distill_artifact(args, field,
                                                               cfg, spec),
                     scan_dirs=scan_dirs)
     if args.solver_artifact and os.path.exists(args.solver_artifact):
@@ -224,7 +238,7 @@ def serve_flow(args) -> None:
             print(f"WARNING: --budgets {','.join(map(str, args.budgets))} "
                   f"ignored; the loaded artifact serves {artifact.budgets}")
     else:
-        artifact = zoo.get(_requested_spec(args), log=print)
+        artifact = zoo.get(requested_spec(args), log=print)
 
     update_fn = None
     if args.kernel_update:
@@ -260,7 +274,7 @@ def serve_flow(args) -> None:
             key = jax.random.PRNGKey(1000 + req)
             latents = (sampler.sample(cond, key, budget=nfe) if anytime
                        else sampler.sample(cond, key))
-            tokens = sampler.nearest_tokens(latents)
+            tokens = jax.block_until_ready(sampler.nearest_tokens(latents))
             print(f"request {req}: sampled {tokens.shape} in "
                   f"{(time.time()-t0)*1e3:.0f} ms ({nfe} NFE)")
     print(f"zoo stats: hits={zoo.stats.hits} misses={zoo.stats.misses} "
@@ -359,9 +373,7 @@ def _serve_gateway(args, sampler, cond, request_budgets) -> None:
 
 def serve_decode(args) -> None:
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
-    if args.ckpt:
-        params = checkpointer.restore(args.ckpt, params)
+    params = init_params(args, cfg)
     engine = DecodeEngine(params=params, cfg=cfg, window=args.window,
                           page_size=args.page_size,
                           paged_kernel=args.paged_kernel)
@@ -530,7 +542,9 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--mesh", default="none",
                     choices=["none", "host", "production", "multipod"],
                     help="gateway: shard the backbone over this serving "
-                         "mesh; 'none' = single-device jit")
+                         "mesh; 'none' = single-device jit, 'host' = every "
+                         "local device on the model axis; a mesh the host "
+                         "lacks the devices for is an error")
     ap.add_argument("--kernel-update", action="store_true",
                     help="route the NS solver update through the Pallas "
                          "ns_update kernel (interpret mode off-TPU)")
@@ -602,6 +616,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main() -> None:
+    enable_compile_cache()
     args = build_parser().parse_args()
     if args.profile != "default":
         from repro.launch.profile import maybe_reexec

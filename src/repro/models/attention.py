@@ -45,14 +45,14 @@ class PagedKVCache(NamedTuple):
     out.
     """
 
-    k_pages: Array      # (L, num_pages, page_size, KV, hd) shared pool
-    v_pages: Array      # (L, num_pages, page_size, KV, hd)
+    k_pages: Array      # (L, num_pages, KV, page_size, hd) shared pool
+    v_pages: Array      # (L, num_pages, KV, page_size, hd)
     block_table: Array  # (B, blocks_per_slot) int32 physical page ids
     index: Array        # (B,) int32 tokens already decoded per row
 
     @property
     def page_size(self) -> int:
-        return self.k_pages.shape[-3]
+        return self.k_pages.shape[-2]
 
     @property
     def capacity(self) -> int:
@@ -167,15 +167,12 @@ def attention_forward(
 
     if (context.flash_attention_enabled() and causal and not window
             and L % 256 == 0):
-        # interpret-mode Pallas flash attention: lowers to a blocked while
-        # loop over VMEM-sized tiles — models the TPU kernel's HBM traffic
-        # (no S x S materialization) in the dry-run HLO.
-        from repro.kernels.flash_attention.flash_attention import flash_attention
-        qh = q.reshape(B, L, n_heads, head_dim).transpose(0, 2, 1, 3)
-        out = flash_attention(qh, k.transpose(0, 2, 1, 3),
-                              v.transpose(0, 2, 1, 3), causal=True,
-                              bq=256, bk=256, interpret=True)
-        out = out.transpose(0, 2, 1, 3).reshape(B, L, n_kv, G, head_dim)
+        # Pallas flash attention (no S x S materialization): the compiled
+        # kernel on a TPU, the interpreter elsewhere (``kernels.ops``).
+        from repro.kernels.flash_attention.ops import attend
+
+        out = attend(q.reshape(B, L, n_heads, head_dim), k, v, causal=True)
+        out = out.reshape(B, L, n_kv, G, head_dim)
     elif (qc := context.q_chunk()) and L > qc and L % qc == 0:
         out = _chunked_attend(q, k, v, positions, causal, window, qc)
     else:
@@ -259,7 +256,7 @@ def decode_attention(
 def decode_attention_paged(
     p: dict,
     x: Array,                    # (B, 1, d) — the new token
-    k_pages: Array,              # (num_pages, page_size, KV, hd) one layer
+    k_pages: Array,              # (num_pages, KV, page_size, hd) one layer
     v_pages: Array,
     block_table: Array,          # (B, nb) int32 page ids
     pos: Array,                  # (B,) int32 decode position per row
@@ -289,7 +286,7 @@ def decode_attention_paged(
     B, Lq, _ = x.shape
     assert Lq == 1
     G = n_heads // n_kv
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     nb = block_table.shape[1]
     q = _split_heads(x @ p["wq"], n_heads, head_dim)
     k_new = _split_heads(x @ p["wk"], n_kv, head_dim)
@@ -308,8 +305,8 @@ def decode_attention_paged(
     rows = jnp.arange(B)
     page = block_table[rows, posw // ps]                        # (B,)
     off = posw % ps
-    k_pages = k_pages.at[page, off].set(k_new[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v_new[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[page, :, off].set(k_new[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[page, :, off].set(v_new[:, 0].astype(v_pages.dtype))
 
     qg = q.reshape(B, n_kv, G, head_dim)
     if kernel:
@@ -319,8 +316,10 @@ def decode_attention_paged(
         out = out.reshape(B, 1, n_heads * head_dim).astype(x.dtype)
     else:
         # dense-gather fallback: row b's logical positions, page-major
-        k = k_pages[block_table].reshape(B, nb * ps, n_kv, head_dim)
-        v = v_pages[block_table].reshape(B, nb * ps, n_kv, head_dim)
+        from repro.kernels.flash_attention.ref import gather_pages
+
+        k = gather_pages(k_pages, block_table)
+        v = gather_pages(v_pages, block_table)
         valid = jnp.arange(nb * ps)[None, :] <= pos[:, None]
         out = _grouped_attend(qg[:, None], k.astype(q.dtype),
                               v.astype(q.dtype), valid[:, None, :])

@@ -19,10 +19,12 @@ plus the paper's substrate:
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.tree_util import Partial
 
 from repro.configs.base import ModelConfig
 from repro.core.parametrization import VelocityField
@@ -211,16 +213,25 @@ def velocity(params: dict, cfg: ModelConfig, t: Array, x: Array,
 def velocity_field(params: dict, cfg: ModelConfig, sched: Scheduler,
                    batch: Optional[dict] = None, *, cfg_scale: float = 0.0
                    ) -> VelocityField:
-    """Wrap the model for the BNS sampler, with classifier-free guidance."""
+    """Wrap the model for the BNS sampler, with classifier-free guidance.
 
-    def u(t, x):
-        uc = velocity(params, cfg, t, x, batch)
-        if cfg_scale == 0.0:
-            return uc
-        uu = velocity(params, cfg, t, x, None)
-        return (1.0 + cfg_scale) * uc - cfg_scale * uu
+    ``fn`` is a ``jax.tree_util.Partial`` whose leaves are ``params`` and
+    ``batch``: a jit that takes the field's ``fn`` as an argument receives
+    the backbone weights as inputs instead of baking them into the program
+    as constants (gigabytes of HLO literals at published widths)."""
+    return VelocityField(
+        fn=Partial(functools.partial(_guided_velocity, cfg=cfg,
+                                     cfg_scale=cfg_scale), params, batch),
+        scheduler=sched)
 
-    return VelocityField(fn=u, scheduler=sched)
+
+def _guided_velocity(params: dict, batch: Optional[dict], t: Array, x: Array,
+                     *, cfg: ModelConfig, cfg_scale: float) -> Array:
+    uc = velocity(params, cfg, t, x, batch)
+    if cfg_scale == 0.0:
+        return uc
+    uu = velocity(params, cfg, t, x, None)
+    return (1.0 + cfg_scale) * uc - cfg_scale * uu
 
 
 def cfm_loss(params: dict, cfg: ModelConfig, batch: dict, rng: Array,

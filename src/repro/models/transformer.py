@@ -33,7 +33,6 @@ from repro.models.attention import (
 from repro.models.layers import (
     dense_init,
     rms_norm,
-    stack_layer_params,
     swiglu,
     timestep_embedding,
 )
@@ -73,8 +72,9 @@ def init_flow_head(key: Array, cfg: ModelConfig) -> dict:
 def init_dense_params(key: Array, cfg: ModelConfig, dtype=None) -> dict:
     dtype = dtype or jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers + 3)
-    layers = stack_layer_params([_layer_init(keys[i], cfg)
-                                 for i in range(cfg.n_layers)])
+    # vmapped over the layer keys: the same values as stacking per-layer
+    # inits, in one program body instead of n_layers unrolled copies
+    layers = jax.vmap(lambda k: _layer_init(k, cfg))(keys[:cfg.n_layers])
     params = {
         "embed": dense_init(keys[-3], cfg.vocab, cfg.d_model, scale=1.0),
         "layers": layers,
@@ -153,12 +153,13 @@ def init_caches(cfg: ModelConfig, batch: int, slots: int, dtype=jnp.bfloat16) ->
 def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
                       page_size: int, blocks_per_slot: int,
                       dtype=jnp.bfloat16) -> PagedKVCache:
-    """Paged decode state: a shared (L, num_pages, page_size, KV, hd) pool
+    """Paged decode state: a shared (L, num_pages, KV, page_size, hd) pool
     plus a zeroed per-row block table — all rows start on the reserved
     trash page 0 (see ``PagedKVCache``) until the gateway's page allocator
-    assigns them real pages at admission."""
+    assigns them real pages at admission. Each page of one KV head is a
+    contiguous (page_size, hd) tile, the paged-attention kernel's block."""
     hd = cfg.resolved_head_dim
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, hd)
+    shape = (cfg.n_layers, num_pages, cfg.n_kv_heads, page_size, hd)
     return PagedKVCache(
         k_pages=jnp.zeros(shape, dtype),
         v_pages=jnp.zeros(shape, dtype),

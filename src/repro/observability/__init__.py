@@ -1,7 +1,7 @@
 """repro.observability — serving telemetry: metrics, tracing, export.
 
-Dependency-free (stdlib only; jax is touched lazily and optionally).
-Three pieces, consumed by every serving tier:
+Stdlib only, apart from ``profile_span``, which imports jax's profiler
+when it is first called. Three pieces, consumed by every serving tier:
 
 * ``metrics`` — ``Counter``/``Gauge``/``Histogram`` behind a
   ``MetricsRegistry``; deterministic fixed-log-bucket histograms with
@@ -17,13 +17,10 @@ Three pieces, consumed by every serving tier:
   http.server).
 
 ``profile_span(name)`` wraps device-dispatch legs in a
-``jax.profiler.TraceAnnotation`` when jax is importable (so gateway
-dispatches show up named in a profiler trace) and degrades to a
-null context otherwise — the registry itself never imports jax.
+``jax.profiler.TraceAnnotation``, so gateway dispatches show up named in
+a profiler trace; the registry itself never imports jax.
 """
 from __future__ import annotations
-
-import contextlib
 
 from repro.observability.export import (
     MetricsServer,
@@ -48,20 +45,11 @@ from repro.observability.trace import (
     read_jsonl,
 )
 
-_PROFILE_FACTORY = None
-
-
 def profile_span(name: str):
-    """Context manager naming a dispatch leg in a jax profiler trace;
-    a null context when jax (or its profiler) is unavailable."""
-    global _PROFILE_FACTORY
-    if _PROFILE_FACTORY is None:
-        try:
-            from jax.profiler import TraceAnnotation
-            _PROFILE_FACTORY = TraceAnnotation
-        except Exception:
-            _PROFILE_FACTORY = lambda _name: contextlib.nullcontext()
-    return _PROFILE_FACTORY(name)
+    """Context manager naming a dispatch leg in a jax profiler trace."""
+    from jax.profiler import TraceAnnotation
+
+    return TraceAnnotation(name)
 
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",

@@ -377,7 +377,7 @@ class DecodeEngine:
       the substrate of ``repro.serving.decode.DecodeGateway``.
 
     ``page_size > 0`` pages the slot state for the KV-cache families: the
-    cache becomes a shared ``(L, num_pages, page_size, KV, hd)`` pool plus a
+    cache becomes a shared ``(L, num_pages, KV, page_size, hd)`` pool plus a
     per-row block table (``PagedKVCache``). Page ownership replaces row
     masking for the pool leaves — a masked-off row's in-flight write lands in
     its own pages (overwritten before the row is next read) or in the

@@ -142,22 +142,18 @@ def carry_placer(mesh):
 
 def serving_mesh(name: str):
     """CLI mesh selection: 'none' -> None (single-device jit), 'host' ->
-    the 1x1 smoke mesh, 'production'/'multipod' -> ``launch.mesh`` shapes.
-    Falls back to None with a warning when the host lacks the devices."""
+    every local device on the ``model`` axis, 'production'/'multipod' ->
+    ``launch.mesh`` shapes. Raises when the host lacks the devices for the
+    mesh asked for; it never falls back to fewer devices."""
     if name in (None, "none"):
         return None
     from repro.launch import mesh as mesh_mod
 
-    try:
-        if name == "host":
-            return mesh_mod.make_host_mesh()
-        if name == "production":
-            return mesh_mod.make_production_mesh()
-        if name == "multipod":
-            return mesh_mod.make_production_mesh(multi_pod=True)
-    except Exception as e:
-        print(f"WARNING: cannot build {name!r} mesh ({e}); "
-              "falling back to single-device jit")
-        return None
+    if name == "host":
+        return mesh_mod.make_host_mesh()
+    if name == "production":
+        return mesh_mod.make_production_mesh()
+    if name == "multipod":
+        return mesh_mod.make_production_mesh(multi_pod=True)
     raise ValueError(f"unknown mesh {name!r}; "
                      "choose none|host|production|multipod")

@@ -133,8 +133,8 @@ def test_paged_attention_sweep(B, KV, G, hd, ps, nb, dtype):
     ks = jax.random.split(key, 4)
     num_pages = 1 + B * nb                   # page 0 = reserved trash page
     q = jax.random.normal(ks[0], (B, KV, G, hd), dtype)
-    k_pages = jax.random.normal(ks[1], (num_pages, ps, KV, hd), dtype)
-    v_pages = jax.random.normal(ks[2], (num_pages, ps, KV, hd), dtype)
+    k_pages = jax.random.normal(ks[1], (num_pages, KV, ps, hd), dtype)
+    v_pages = jax.random.normal(ks[2], (num_pages, KV, ps, hd), dtype)
     # each row owns nb distinct pages, in shuffled (non-contiguous) order
     perm = jax.random.permutation(ks[3], num_pages - 1)[:B * nb] + 1
     block_table = perm.reshape(B, nb).astype(jnp.int32)
@@ -154,14 +154,15 @@ def test_paged_attention_ignores_positions_past_length():
     B, KV, G, hd, ps, nb = 1, 2, 2, 32, 8, 2
     ks = jax.random.split(jax.random.PRNGKey(3), 3)
     q = jax.random.normal(ks[0], (B, KV, G, hd))
-    k_pages = jax.random.normal(ks[1], (1 + nb, ps, KV, hd))
-    v_pages = jax.random.normal(ks[2], (1 + nb, ps, KV, hd))
+    k_pages = jax.random.normal(ks[1], (1 + nb, KV, ps, hd))
+    v_pages = jax.random.normal(ks[2], (1 + nb, KV, ps, hd))
     table = jnp.asarray([[1, 2]], jnp.int32)
     lengths = jnp.asarray([5], jnp.int32)
-    base = paged_attention(q, k_pages, v_pages, table, lengths)
-    poisoned_k = k_pages.at[1, 5:].set(1e4).at[2].set(-1e4)
-    poisoned_v = v_pages.at[1, 5:].set(1e4).at[2].set(-1e4)
-    out = paged_attention(q, poisoned_k, poisoned_v, table, lengths)
+    base = paged_attention(q, k_pages, v_pages, table, lengths, interpret=True)
+    poisoned_k = k_pages.at[1, :, 5:].set(1e4).at[2].set(-1e4)
+    poisoned_v = v_pages.at[1, :, 5:].set(1e4).at[2].set(-1e4)
+    out = paged_attention(q, poisoned_k, poisoned_v, table, lengths,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(base), atol=1e-5)
 
 

@@ -168,3 +168,13 @@ def test_decode_mode_gateway_continuous_batching():
     assert "slot_occ=" in out and "tok/s=" in out
     # a freed slot was refilled mid-flight at least once
     assert "joins=0" not in out
+
+
+def test_profile_tuned_reexecs_and_serves():
+    """--profile tuned re-execs under its XLA flags and the child runs: the
+    installed XLA aborts on any flag it does not know."""
+    r = _run("--arch", "rwkv6-7b", "--mode", "decode", "--batch", "1",
+             "--steps", "2", "--profile", "tuned")
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert "re-exec under 'tuned' profile" in r.stdout
+    assert "decoded 2 tokens" in r.stdout

@@ -9,15 +9,8 @@ from repro.configs import ARCHS, get_config
 from repro.distributed.sharding import cache_spec, param_specs, state_specs
 from repro.models import model as M
 
-def _abstract_mesh(sizes, names):
-    try:
-        return AbstractMesh(sizes, names)
-    except TypeError:   # jax<0.5 signature: AbstractMesh(((name, size), ...))
-        return AbstractMesh(tuple(zip(names, sizes)))
-
-
-MESH = _abstract_mesh((16, 16), ("data", "model"))
-MESH_MP = _abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def shapes_of(cfg):

@@ -1,0 +1,142 @@
+"""Compile rehearsal for a TPU v5e that is described, not attached.
+
+The TPU compiler refuses what interpret mode accepts (block shapes off the
+(8, 128) tiling, VMEM overruns, programs too large for HBM), so the kernels
+of the serving path and one whole step of each engine are compiled here at
+yi-6b's published widths (depth cut to 2 for the whole steps). Nothing
+runs: these say nothing about results or speed.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process at a time may load the TPU library, and every xdist worker
+imports this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.core.parametrization import VelocityField
+from repro.core.schedulers import get_scheduler
+from repro.kernels.flash_attention.flash_attention import flash_attention
+from repro.kernels.flash_attention.paged_attention import paged_attention
+from repro.kernels.ns_update.ns_update import ns_update_nd
+from repro.kernels.ns_update.ops import make_update_fn
+from repro.models import model as M
+from repro.serving.engine import DecodeEngine, FlowSampler
+from repro.solvers.registry import build_ns
+
+HBM_BYTES = 16 * 2**30      # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without one
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+    compilation_cache.reset_cache()
+
+
+def _spec(sharding, shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _specs(sharding, tree):
+    return jax.tree.map(lambda x: _spec(sharding, x.shape, x.dtype), tree)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _yi6b_depth2():
+    return dataclasses.replace(get_config("yi-6b"), n_layers=2)
+
+
+@pytest.mark.parametrize("batch", [1, 8, 12])
+def test_ns_update_compiles_at_serving_bucket(one_chip, batch):
+    n, shape = 8, (16, 64)          # 16 latent tokens of yi-6b's latent_dim
+    _compile(lambda x0, u, a, w: ns_update_nd(x0, u, a, w, interpret=False),
+             _spec(one_chip, (batch,) + shape),
+             _spec(one_chip, (n, batch) + shape),
+             _spec(one_chip, ()), _spec(one_chip, (n,)))
+
+
+@pytest.mark.parametrize("seq", [256, 1024])
+def test_flash_attention_compiles_at_yi6b_widths(one_chip, seq):
+    B, H, KV, hd = 1, 32, 4, 128
+    _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                             interpret=False),
+             _spec(one_chip, (B, H, seq, hd), jnp.bfloat16),
+             _spec(one_chip, (B, KV, seq, hd), jnp.bfloat16),
+             _spec(one_chip, (B, KV, seq, hd), jnp.bfloat16))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_compiles_at_yi6b_widths(one_chip, dtype):
+    B, KV, G, hd, ps, nb = 8, 4, 8, 128, 16, 8
+    pool = (1 + B * nb, KV, ps, hd)
+    _compile(lambda q, k, v, bt, ln: paged_attention(q, k, v, bt, ln,
+                                                     interpret=False),
+             _spec(one_chip, (B, KV, G, hd), dtype),
+             _spec(one_chip, pool, dtype), _spec(one_chip, pool, dtype),
+             _spec(one_chip, (B, nb), jnp.int32),
+             _spec(one_chip, (B,), jnp.int32))
+
+
+def test_flow_step_compiles_at_yi6b_widths(one_chip):
+    """FlowSampler's serving program: 8 NFE through the backbone with the
+    Pallas NS update, 8 requests x 16 latent tokens."""
+    cfg = _yi6b_depth2()
+    sched = get_scheduler("fm_ot")
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    solver = build_ns("euler", 8, VelocityField(fn=None, scheduler=sched))
+    sampler = FlowSampler(params=None, cfg=cfg, sched=sched, solver=solver,
+                          update_fn=make_update_fn(use_kernel=True,
+                                                   interpret=False))
+    compiled = sampler._sample.lower(
+        _specs(one_chip, params), _specs(one_chip, solver),
+        {"tokens": _spec(one_chip, (8, 16), jnp.int32)},
+        _spec(one_chip, (8, 16, cfg.latent_dim))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_paged_decode_step_compiles_at_yi6b_widths(one_chip, monkeypatch):
+    """DecodeEngine's slot step over a paged pool through the Pallas
+    paged-attention kernel (4 slots x 128 positions, page 16)."""
+    import repro.kernels.flash_attention.ops as attention_ops
+
+    # the engine picks interpret mode from the backend, which is the CPU here
+    monkeypatch.setattr(attention_ops, "interpret_mode", lambda: False)
+    cfg = _yi6b_depth2()
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    engine = DecodeEngine(params=None, cfg=cfg, page_size=16,
+                          paged_kernel=True)
+    state = jax.eval_shape(lambda: engine.init_slot_state(4, 128))
+    compiled = engine._step_slots.lower(
+        _specs(one_chip, params), _spec(one_chip, (4,), jnp.int32),
+        _specs(one_chip, state), _spec(one_chip, (4,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
