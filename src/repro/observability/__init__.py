@@ -1,7 +1,8 @@
 """repro.observability — serving telemetry: metrics, tracing, export.
 
-Stdlib only, apart from ``profile_span``, which imports jax's profiler
-when it is first called. Three pieces, consumed by every serving tier:
+Stdlib only, apart from ``profile_span`` and ``compile_count``, which
+import jax's profiler and monitoring when they are first called. Three
+pieces, consumed by every serving tier:
 
 * ``metrics`` — ``Counter``/``Gauge``/``Histogram`` behind a
   ``MetricsRegistry``; deterministic fixed-log-bucket histograms with
@@ -16,11 +17,15 @@ when it is first called. Three pieces, consumed by every serving tier:
   ``MetricsServer`` (``/metrics`` + ``/metrics.json`` over stdlib
   http.server).
 
-``profile_span(name)`` wraps device-dispatch legs in a
-``jax.profiler.TraceAnnotation``, so gateway dispatches show up named in
-a profiler trace; the registry itself never imports jax.
+``profile_span(name, **args)`` names one phase of a serving thread's
+tick in a ``jax.profiler.TraceAnnotation``: the spans share the device
+trace's clock, and ``args`` (row counts) land as the event's stats while
+its name stays stable. ``compile_count`` reads one process-wide listener
+on XLA backend compilations. The registry itself never imports jax.
 """
 from __future__ import annotations
+
+import threading
 
 from repro.observability.export import (
     MetricsServer,
@@ -45,11 +50,48 @@ from repro.observability.trace import (
     read_jsonl,
 )
 
-def profile_span(name: str):
-    """Context manager naming a dispatch leg in a jax profiler trace."""
-    from jax.profiler import TraceAnnotation
+_TraceAnnotation = None
 
-    return TraceAnnotation(name)
+
+def profile_span(name: str, **args):
+    """Context manager naming one phase of the serving thread in a jax
+    profiler trace; ``args`` become the event's stats. About a
+    microsecond with the profiler off."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _TraceAnnotation = TraceAnnotation
+    return _TraceAnnotation(name, **args)
+
+
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compiles = 0
+_compile_lock = threading.Lock()
+_listening = False
+
+
+def _on_compile_event(name: str, _secs: float, **_kw) -> None:
+    global _compiles
+    if name == _COMPILE_EVENT:
+        with _compile_lock:
+            _compiles += 1
+
+
+def compile_count() -> int:
+    """XLA backend compilations in this process since the first call.
+    The first call registers ONE ``jax.monitoring`` listener for the
+    whole process; every later call (every gateway's ``compilations``
+    gauge) reads the same count."""
+    global _listening
+    with _compile_lock:
+        if not _listening:
+            import jax.monitoring
+
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_compile_event)
+            _listening = True
+        return _compiles
 
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
@@ -57,4 +99,4 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "percentile_from_buckets", "bucket_bounds_at", "to_prometheus",
            "TraceRecorder", "NullRecorder", "NULL_RECORDER", "read_jsonl",
            "MetricsServer", "StatsPrinter", "format_stats_line",
-           "profile_span"]
+           "profile_span", "compile_count"]

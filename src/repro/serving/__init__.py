@@ -108,15 +108,20 @@ Metric schema (name — type — labels — emitting tiers):
 ``queue_depth``         gauge     —            all gateways (lazy)
 ``inflight``            gauge     —            all gateways (lazy)
 ``jit_programs``        gauge     —            all dispatching tiers
+``compilations``        gauge     —            all gateways (one
+                                               process-wide listener)
 ``pages_in_use``        gauge     —            decode (``PageAllocator``)
 ``peak_pages``          gauge     —            decode (``PageAllocator``)
 ``page_pool_total``     gauge     —            decode (``PageAllocator``)
 ``wait_ms``             histogram —            all gateways (submit ->
                                                settle; count ==
                                                completed)
-``host_assembly_ms``    histogram —            gateway
+``host_assembly_ms``    histogram —            gateway, continuous
 ``device_dispatch_ms``  histogram —            gateway, continuous,
-                                               decode
+                                               decode (the enqueue)
+``device_wait_ms``      histogram —            gateway, continuous,
+                                               decode (the readback)
+``forwards_by_rows``    counter   ``rows``     gateway, continuous
 ======================= ========= ============ =========================
 
 Module map:

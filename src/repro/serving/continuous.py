@@ -294,8 +294,9 @@ class ContinuousGateway(Gateway):
         ran = 0
         with self._plan_lock:
             if self.slo is not None:
-                self._shed_expired()
-                self.scheduler.lead_ms = self._dispatch_cost_ms()
+                with profile_span("continuous.plan"):
+                    self._shed_expired()
+                    self.scheduler.lead_ms = self._dispatch_cost_ms()
             if self._traj is not None:
                 try:
                     self._advance_leg()
@@ -310,10 +311,12 @@ class ContinuousGateway(Gateway):
                 # gets first claim on the pending queue — a trajectory costs
                 # what a mixed flush costs but its slots refill at every
                 # later boundary, so it must outrank the flush planner
-                starters = self.scheduler.plan_start(
-                    self.queue.snapshot(), self.clock(), force=force)
+                with profile_span("continuous.plan"):
+                    starters = self.scheduler.plan_start(
+                        self.queue.snapshot(), self.clock(), force=force)
+                    if starters:
+                        self._take(starters)
                 if starters:
-                    self._take(starters)
                     try:
                         self._start_trajectory(starters, self.clock())
                     except BaseException as exc:  # noqa: BLE001
@@ -323,9 +326,10 @@ class ContinuousGateway(Gateway):
                     ran += 1
             # interleave flushes: whatever neither joined nor started still
             # obeys the flush-only rules (full buckets now, partials aged)
-            batches = self.scheduler.plan(
-                self.queue.snapshot(), self.clock(), force=force)
-            self._take([e for b in batches for e in b.entries])
+            with profile_span("continuous.plan"):
+                batches = self.scheduler.plan(
+                    self.queue.snapshot(), self.clock(), force=force)
+                self._take([e for b in batches for e in b.entries])
         return ran + self._run_batches(batches)
 
     def _estimate_wait_ms(self, entry) -> float:
@@ -334,13 +338,15 @@ class ContinuousGateway(Gateway):
         below one whole dispatch (the flush model's unit) — joiners ride
         legs already paid for. The queue therefore drains at the OBSERVED
         device-time-per-settle rate, which the registry already tracks
-        exactly (``device_dispatch_ms.sum`` over ``completed``). Before
-        the first settle there is nothing to observe and the inherited
-        flush batch model — seeded by ``slo.default_cost_ms`` — stands
-        in."""
+        exactly (``device_dispatch_ms.sum`` — the enqueues — plus
+        ``device_wait_ms.sum`` — the waits on results — over
+        ``completed``). Before the first settle there is nothing to
+        observe and the inherited flush batch model — seeded by
+        ``slo.default_cost_ms`` — stands in."""
         with self._stats_lock:
             completed = self._m.completed.value
-            device_ms = self._m.device_dispatch_ms.sum
+            device_ms = (self._m.device_dispatch_ms.sum
+                         + self._m.device_wait_ms.sum)
             inflight = self._inflight
         if completed and device_ms > 0.0:
             # work ahead of us = queued entries plus the trajectory rows
@@ -354,19 +360,24 @@ class ContinuousGateway(Gateway):
         first leg runs on the next tick; waits end here, at admission)."""
         slots = self.scheduler.max_slots
         pad = slots - len(starters)
-        x0_np, tokens = assemble_rows(starters, slots)
+        with profile_span("continuous.assemble", rows=len(starters),
+                          bucket=slots):
+            t0 = self.clock()
+            x0_np, tokens = assemble_rows(starters, slots)
+            traj = _Trajectory(carry=None,
+                               entries=list(starters) + [None] * pad,
+                               shape_key=starters[0].shape_key, tokens=tokens)
+            carry = self.sampler.carry_start(traj.cond(), jnp.asarray(x0_np))
+            if self._place_carry is not None:
+                carry = self._place_carry(carry)
+            assembly_ms = (self.clock() - t0) * 1e3
         for e in starters:
             e.t_admit, e.join_step = now, 0
-        traj = _Trajectory(carry=None, entries=list(starters) + [None] * pad,
-                           shape_key=starters[0].shape_key, tokens=tokens)
-        with profile_span(f"continuous.start.k{slots}"):
-            carry = self.sampler.carry_start(traj.cond(), jnp.asarray(x0_np))
-        if self._place_carry is not None:
-            carry = self._place_carry(carry)
         traj.carry = carry
         self._traj = traj
         with self._stats_lock:
             self._m.trajectories.inc()
+            self._m.host_assembly_ms.observe(assembly_ms)
             self._note_program(f"start/k{slots}")
         rec = self.recorder
         if rec:
@@ -382,12 +393,13 @@ class ContinuousGateway(Gateway):
         boundary = self.scheduler.next_boundary(step)
         assert boundary is not None, "trajectory ran past the top budget"
         active = traj.active()
-        t0 = self.clock()   # gateway clock: fake-clock benches feed the
-        #                     SLO cost model simulated dispatch times
-        with profile_span(f"continuous.leg.{step}-{boundary}"):
+        with profile_span(f"continuous.leg.{step}-{boundary}",
+                          live=len(active), bucket=self.scheduler.max_slots):
+            t0 = self.clock()   # gateway clock: fake-clock benches feed the
+            #                     SLO cost model simulated dispatch times
             carry, exits = self.sampler.carry_extend(traj.cond(), traj.carry,
                                                      boundary)
-        leg_ms = (self.clock() - t0) * 1e3
+            leg_ms = (self.clock() - t0) * 1e3
         traj.carry = carry
         # a max_leg-clipped stop is a control point, not an exit boundary:
         # nothing releases or joins there, but interleaved flushes can run
@@ -400,8 +412,12 @@ class ContinuousGateway(Gateway):
         streaming = [(si, e) for si, e in active
                      if is_exit and e.sink is not None
                      and e.served > boundary]
-        latents = (np.asarray(exits[boundary])
-                   if (released or streaming) else None)
+        latents = wait_ms = None
+        if released or streaming:
+            with profile_span(f"continuous.sync.{step}-{boundary}"):
+                t1 = self.clock()
+                latents = np.asarray(exits[boundary])
+                wait_ms = (self.clock() - t1) * 1e3
         with self._stats_lock:
             m = self._m
             m.legs.inc()
@@ -410,6 +426,9 @@ class ContinuousGateway(Gateway):
             m.slot_steps_total.inc(
                 self.scheduler.max_slots * (boundary - step))
             m.device_dispatch_ms.observe(leg_ms)
+            if wait_ms is not None:
+                m.device_wait_ms.observe(wait_ms)
+            self._note_rows(len(active), boundary - step)
             self._note_program(f"leg/{step}-{boundary}")
             if active and active[0][1].native_shape is not None:
                 # per-tier occupancy, weighted by leg steps (the slot-
@@ -421,18 +440,24 @@ class ContinuousGateway(Gateway):
                     tier,
                     steps * sum(e.native_shape[0] for _, e in active),
                     steps * self.scheduler.max_slots * tier[0])
-        for si, e in streaming:
-            e.sink.partial(crop_row(latents[si], e.native_shape),
-                           boundary=boundary)
-        for si, e in released:
-            self._release(traj, si, e, crop_row(latents[si], e.native_shape),
-                          boundary, len(active))
+        if latents is not None:
+            with profile_span(f"continuous.release.{boundary}",
+                              rows=len(released)):
+                for si, e in streaming:
+                    e.sink.partial(crop_row(latents[si], e.native_shape),
+                                   boundary=boundary)
+                for si, e in released:
+                    self._release(traj, si, e,
+                                  crop_row(latents[si], e.native_shape),
+                                  boundary, len(active))
         if is_exit:
-            joiners = self.scheduler.plan_joins(
-                self.queue.snapshot(), boundary, len(traj.free_slots()),
-                traj.shape_key)
+            with profile_span("continuous.plan"):
+                joiners = self.scheduler.plan_joins(
+                    self.queue.snapshot(), boundary, len(traj.free_slots()),
+                    traj.shape_key)
+                if joiners:
+                    self._take(joiners)
             if joiners:
-                self._take(joiners)
                 try:
                     self._admit(traj, joiners, boundary)
                 except BaseException as exc:  # noqa: BLE001
@@ -501,21 +526,25 @@ class ContinuousGateway(Gateway):
                  if e.paused is None or e.paused.step > boundary]
         resumed = [e for e in joiners
                    if e.paused is not None and e.paused.step <= boundary]
-        cols: dict[int, tuple] = {}   # uid -> (x0 row, U column, x row)
+        carriers: list[tuple] = []    # (entries, carry holding their rows)
         programs: list[str] = []
-        prefix_forwards = 0
+        assembly_ms: list[float] = []
+        rows_steps: list[tuple] = []  # (real rows, NFE steps) per dispatch
         if fresh:
             k = len(fresh)
             bucket = self.scheduler.join_bucket(k)
-            x0_np, t_np = assemble_rows(fresh, bucket)
-            cond = None if t_np is None else {"tokens": jnp.asarray(t_np)}
-            with profile_span(f"continuous.join.{boundary}/k{bucket}"):
+            with profile_span("continuous.assemble", rows=k, bucket=bucket):
+                t0 = self.clock()
+                x0_np, t_np = assemble_rows(fresh, bucket)
+                cond = None if t_np is None else {"tokens": jnp.asarray(t_np)}
                 prefix = self.sampler.carry_start(cond, jnp.asarray(x0_np))
+                assembly_ms.append((self.clock() - t0) * 1e3)
+            with profile_span(f"continuous.join.{boundary}/k{bucket}",
+                              rows=k):
                 prefix, _ = self.sampler.carry_extend(cond, prefix, boundary)
-            prefix_forwards += boundary
+            rows_steps.append((k, boundary))
             programs.append(f"join/{boundary}-k{bucket}")
-            for i, e in enumerate(fresh):
-                cols[e.uid] = (prefix.x0[i], prefix.U[:, i], prefix.x[i])
+            carriers.append((fresh, prefix))
         by_step: dict[int, list] = {}
         for e in resumed:
             by_step.setdefault(e.paused.step, []).append(e)
@@ -523,33 +552,42 @@ class ContinuousGateway(Gateway):
             group = by_step[s]
             k = len(group)
             bucket = self.scheduler.join_bucket(k)
-            x0_np, u_np, x_np, t_np = self._stack_paused(group, bucket)
-            rcarry = type(traj.carry)(
-                x0=jnp.asarray(x0_np), U=jnp.asarray(u_np),
-                x=jnp.asarray(x_np), step=s)
-            if s < boundary:
-                cond = (None if t_np is None
+            with profile_span("continuous.assemble", rows=k, bucket=bucket):
+                t0 = self.clock()
+                x0_np, u_np, x_np, t_np = self._stack_paused(group, bucket)
+                rcarry = type(traj.carry)(
+                    x0=jnp.asarray(x0_np), U=jnp.asarray(u_np),
+                    x=jnp.asarray(x_np), step=s)
+                cond = (None if t_np is None or s == boundary
                         else {"tokens": jnp.asarray(t_np)})
+                assembly_ms.append((self.clock() - t0) * 1e3)
+            if s < boundary:
                 with profile_span(
-                        f"continuous.resume.{s}-{boundary}/k{bucket}"):
+                        f"continuous.resume.{s}-{boundary}/k{bucket}",
+                        rows=k):
                     rcarry, _ = self.sampler.carry_extend(cond, rcarry,
                                                           boundary)
-                prefix_forwards += boundary - s
+                rows_steps.append((k, boundary - s))
                 programs.append(f"resume/{s}-{boundary}-k{bucket}")
-            for i, e in enumerate(group):
-                cols[e.uid] = (rcarry.x0[i], rcarry.U[:, i], rcarry.x[i])
+            carriers.append((group, rcarry))
         free = traj.free_slots()[:len(joiners)]
-        idx = jnp.asarray(free)
-        carry = traj.carry
-        carry = carry._replace(
-            x0=carry.x0.at[idx].set(
-                jnp.stack([cols[e.uid][0] for e in joiners])),
-            U=carry.U.at[:, idx].set(
-                jnp.stack([cols[e.uid][1] for e in joiners], axis=1)),
-            x=carry.x.at[idx].set(
-                jnp.stack([cols[e.uid][2] for e in joiners])))
-        if self._place_carry is not None:
-            carry = self._place_carry(carry)
+        with profile_span(f"continuous.scatter.{boundary}",
+                          rows=len(joiners)):
+            cols: dict[int, tuple] = {}   # uid -> (x0 row, U column, x row)
+            for group, c in carriers:
+                for i, e in enumerate(group):
+                    cols[e.uid] = (c.x0[i], c.U[:, i], c.x[i])
+            idx = jnp.asarray(free)
+            carry = traj.carry
+            carry = carry._replace(
+                x0=carry.x0.at[idx].set(
+                    jnp.stack([cols[e.uid][0] for e in joiners])),
+                U=carry.U.at[:, idx].set(
+                    jnp.stack([cols[e.uid][1] for e in joiners], axis=1)),
+                x=carry.x.at[idx].set(
+                    jnp.stack([cols[e.uid][2] for e in joiners])))
+            if self._place_carry is not None:
+                carry = self._place_carry(carry)
         traj.carry = carry
         now = self.clock()
         rec = self.recorder
@@ -566,11 +604,16 @@ class ContinuousGateway(Gateway):
                 rec.event(e.uid, "join", now, host=self._host,
                           boundary=boundary, slot=si,
                           resumed=e in resumed)
+        prefix_forwards = sum(steps for _, steps in rows_steps)
         with self._stats_lock:
             m = self._m
             m.joins.inc(len(joiners))
             m.forwards.inc(prefix_forwards)
             m.join_forwards.inc(prefix_forwards)
+            for ms in assembly_ms:
+                m.host_assembly_ms.observe(ms)
+            for rows, steps in rows_steps:
+                self._note_rows(rows, steps)
             for program in programs:
                 self._note_program(program)
 
@@ -607,21 +650,26 @@ class ContinuousGateway(Gateway):
         snapshotted to host (``PausedCarry``) and the victim goes BACK to
         the queue; a later ``plan_joins`` resumes it for only the
         boundary-gap forwards, bit-identical to an unpreempted run."""
-        pairs = self.scheduler.plan_preemptions(
-            self.queue.snapshot(), boundary, traj.active(),
-            len(traj.free_slots()), traj.shape_key)
+        with profile_span("continuous.plan"):
+            pairs = self.scheduler.plan_preemptions(
+                self.queue.snapshot(), boundary, traj.active(),
+                len(traj.free_slots()), traj.shape_key)
         if not pairs:
             return
         carry = traj.carry
         rec = self.recorder
+        with profile_span(f"continuous.sync.preempt.{boundary}"):
+            t0 = self.clock()
+            snaps = [PausedCarry(step=boundary,
+                                 x0=np.asarray(carry.x0[si]),
+                                 U=np.asarray(carry.U[:, si]),
+                                 x=np.asarray(carry.x[si]))
+                     for si, _, _ in pairs]
+            wait_ms = (self.clock() - t0) * 1e3
         now = self.clock()
         urgents = []
-        for si, victim, urgent in pairs:
-            victim.paused = PausedCarry(
-                step=boundary,
-                x0=np.asarray(carry.x0[si]),
-                U=np.asarray(carry.U[:, si]),
-                x=np.asarray(carry.x[si]))
+        for (si, victim, urgent), snap in zip(pairs, snaps):
+            victim.paused = snap
             traj.entries[si] = None
             # back to the queue: still accepted (submitted already
             # counted), no longer in flight until it rejoins
@@ -633,6 +681,7 @@ class ContinuousGateway(Gateway):
                           boundary=boundary, slot=si, by=urgent.uid)
         with self._stats_lock:
             self._m.preemptions.inc(len(pairs))
+            self._m.device_wait_ms.observe(wait_ms)
         self._take(urgents)
         try:
             self._admit(traj, urgents, boundary)

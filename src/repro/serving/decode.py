@@ -401,7 +401,10 @@ class DecodeGateway(GatewayBase):
                 return 1
             step_ms = (self.clock() - t0) * 1e3
             self._state = state
-            nxt = np.asarray(nxt)
+            with profile_span(f"decode.sync.k{self.max_slots}"):
+                t1 = self.clock()
+                nxt = np.asarray(nxt)
+                wait_ms = (self.clock() - t1) * 1e3
             self._steps += 1
             with self._stats_lock:
                 m = self._m
@@ -412,6 +415,7 @@ class DecodeGateway(GatewayBase):
                 m.slot_steps_active.inc(int(active.sum()))
                 m.slot_steps_total.inc(self.max_slots)
                 m.device_dispatch_ms.observe(step_ms)
+                m.device_wait_ms.observe(wait_ms)
                 self._note_program(f"step/k{self.max_slots}")
             for i, slot in enumerate(self._slots):
                 if slot is not None and active[i]:

@@ -93,7 +93,12 @@ from typing import Any, Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from repro.observability import MetricsRegistry, NULL_RECORDER, profile_span
+from repro.observability import (
+    MetricsRegistry,
+    NULL_RECORDER,
+    compile_count,
+    profile_span,
+)
 from repro.serving.slo import (
     AdmissionRejected,
     DeadlineExceeded,
@@ -484,6 +489,9 @@ METRIC_SCHEMA: tuple = (
     ("inflight", "gauge", "entries taken off the queue, unresolved"),
     ("jit_programs", "gauge", "distinct jit programs dispatched "
                               "(a climb in steady state = retracing)"),
+    ("compilations", "gauge",
+     "XLA backend compilations in this process (a climb while "
+     "jit_programs stays flat = a retrace under a known label)"),
     ("tier_occupancy", "gauge",
      "native/padded position-row share of dispatched work, per shape "
      "tier (labelled tier=<shape>; the unlabelled base stays 0 — "
@@ -495,7 +503,9 @@ METRIC_SCHEMA: tuple = (
     ("host_assembly_ms", "histogram",
      "host-side batch assembly + transfer per dispatch (ms)"),
     ("device_dispatch_ms", "histogram",
-     "device dispatch wall time per batch/leg (ms)"),
+     "enqueue of one device dispatch per batch/leg/step (ms)"),
+    ("device_wait_ms", "histogram",
+     "serving thread blocked on device results, per readback (ms)"),
 )
 
 
@@ -629,6 +639,8 @@ class GatewayBase:
         # double-booking every transition
         self._m.queue_depth.set_fn(self.queue.depth)
         self._m.inflight.set_fn(lambda: self._inflight)
+        compile_count()     # the process-wide listener counts from here
+        self._m.compilations.set_fn(compile_count)
         self._closed = False
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -681,6 +693,18 @@ class GatewayBase:
         self.metrics.counter("dispatches",
                              "dispatches per compiled jit program",
                              labels={"program": program}).inc()
+
+    def _note_rows(self, rows: int, steps: int) -> None:
+        """Per-dispatch row accounting (caller holds ``_stats_lock``):
+        ``steps`` NFE steps dispatched, each needed by ``rows`` real rows,
+        under the labelled ``forwards_by_rows`` counter. Padded rows never
+        count, so sum(rows x steps) over the labels is the steps the
+        requests themselves need."""
+        if rows > 0 and steps > 0:
+            self.metrics.counter(
+                "forwards_by_rows",
+                "NFE steps dispatched, by the real rows that need them",
+                labels={"rows": str(rows)}).inc(steps)
 
     def _note_tier(self, tier_shape: tuple, real: int, padded: int) -> None:
         """Per-tier occupancy accounting (caller holds ``_stats_lock``):
@@ -780,16 +804,18 @@ class GatewayBase:
     # -- SLO scheduling (repro.serving.slo) -----------------------------------
 
     def _dispatch_cost_ms(self) -> float:
-        """Observed mean cost of one dispatch (assembly + device), read
-        from the registry's own histograms — the admission cost model
-        calibrates itself from live traffic. Before the first dispatch it
-        falls back to ``slo.default_cost_ms`` (0 = optimistic accept)."""
+        """Observed mean cost of one dispatch (assembly + enqueue + the
+        wait for its results), read from the registry's own histograms —
+        the admission cost model calibrates itself from live traffic.
+        Before the first dispatch it falls back to ``slo.default_cost_ms``
+        (0 = optimistic accept)."""
         with self._stats_lock:
             dispatch = hist_mean(self._m.device_dispatch_ms)
+            wait = hist_mean(self._m.device_wait_ms)
             assembly = hist_mean(self._m.host_assembly_ms)
         if dispatch is None:
             return self.slo.default_cost_ms if self.slo else 0.0
-        return dispatch + (assembly or 0.0)
+        return dispatch + (wait or 0.0) + (assembly or 0.0)
 
     def _estimate_wait_ms(self, entry) -> float:
         """Modeled time until ``entry`` would settle, given the current
@@ -956,11 +982,20 @@ class GatewayBase:
 
     # -- lifecycle ------------------------------------------------------------
 
+    def _tick(self, force: bool = False) -> int:
+        """One ``pump`` inside the ``gateway.pump`` span: the parent of
+        every span the tick opens, so the serving thread's time is named
+        end to end in a profiler trace."""
+        with profile_span("gateway.pump"):
+            return self.pump(force=force)
+
     def serve_forever(self, poll_s: float = 0.001) -> None:
-        """Pump until ``stop``; sleeps ``poll_s`` when there is no work."""
+        """Pump until ``stop``; sleeps ``poll_s`` (the ``gateway.idle``
+        span) when there is no work."""
         while not self._stop.is_set():
-            if self.pump() == 0:
-                time.sleep(poll_s)
+            if self._tick() == 0:
+                with profile_span("gateway.idle"):
+                    time.sleep(poll_s)
 
     def start(self, poll_s: float = 0.001) -> threading.Thread:
         if self._thread is not None and self._thread.is_alive():
@@ -1011,8 +1046,9 @@ class GatewayBase:
                     f"completed={snap['completed']}/{snap['submitted']}",
                     snap, snapshot=registry,
                     spans=rec.open_spans() if rec else {})
-            if self.pump(force=True) == 0:
-                time.sleep(5e-4)       # a concurrent pump holds the work
+            if self._tick(force=True) == 0:
+                with profile_span("gateway.idle"):
+                    time.sleep(5e-4)   # a concurrent pump holds the work
 
     def stop(self) -> None:
         self._stop.set()
@@ -1166,7 +1202,7 @@ class Gateway(GatewayBase):
 
     def pump(self, force: bool = False) -> int:
         """Plan ready batches and execute them; returns how many ran."""
-        with self._plan_lock:
+        with self._plan_lock, profile_span("gateway.plan"):
             if self.slo is not None:
                 self._shed_expired()
                 self.scheduler.lead_ms = self._dispatch_cost_ms()
@@ -1203,6 +1239,7 @@ class Gateway(GatewayBase):
         import numpy as np
 
         es = batch.entries
+        rows = len(es)
         dispatched = self.clock()   # wait_ms is QUEUE time, ending here —
         #                             not device/compile time
         program = (f"b{'mix' if batch.mixed else batch.budget}"
@@ -1213,29 +1250,51 @@ class Gateway(GatewayBase):
             # Timing runs on the GATEWAY clock (production: time.monotonic,
             # same resolution as perf_counter) so fake-clock benches feed
             # the SLO cost model simulated, deterministic dispatch times
-            t0 = self.clock()
-            x0_np, t_np = assemble_rows(es, batch.bucket)
-            x0 = jnp.asarray(x0_np)
-            cond = None if t_np is None else {"tokens": jnp.asarray(t_np)}
-            if self._place is not None:
-                cond, x0 = self._place(cond, x0)
-            t1 = self.clock()
-            with profile_span(f"gateway.dispatch.{program}"):
+            with profile_span("gateway.assemble", rows=rows,
+                              bucket=batch.bucket):
+                t0 = self.clock()
+                x0_np, t_np = assemble_rows(es, batch.bucket)
+                x0 = jnp.asarray(x0_np)
+                cond = None if t_np is None else {"tokens": jnp.asarray(t_np)}
+                if self._place is not None:
+                    cond, x0 = self._place(cond, x0)
+                t1 = self.clock()
+            # the dispatch is the enqueue alone; the readback is the sync
+            with profile_span(f"gateway.dispatch.{program}", rows=rows,
+                              bucket=batch.bucket):
                 if batch.mixed:
                     outs = self.sampler.sample_all_from(cond, x0)
                     nfe = max(self.sampler.budgets)
+                else:
+                    out = self.sampler.sample_from(cond, x0, batch.budget)
+                    nfe = batch.budget
+                t2 = self.clock()
+            with profile_span(f"gateway.sync.{program}"):
+                if batch.mixed:
                     host = {m: np.asarray(outs[m])
                             for m in {e.served for e in es}}
-                    rows = [host[e.served][i] for i, e in enumerate(es)]
+                    lat_rows = [host[e.served][i] for i, e in enumerate(es)]
                 else:
-                    lat = np.asarray(
-                        self.sampler.sample_from(cond, x0, batch.budget))
-                    nfe = batch.budget
-                    rows = [lat[i] for i in range(len(es))]
-            t2 = self.clock()
+                    lat = np.asarray(out)
+                    lat_rows = [lat[i] for i in range(rows)]
+                t3 = self.clock()
         except Exception as exc:
             self._fail_entries(es, exc, count_all=True)
             return
+        with profile_span("gateway.settle", rows=rows):
+            self._settle_batch(batch, program, nfe, lat_rows, dispatched,
+                               ((t1 - t0) * 1e3, (t2 - t1) * 1e3,
+                                (t3 - t2) * 1e3))
+
+    def _settle_batch(self, batch: Batch, program: str, nfe: int,
+                      lat_rows: list, dispatched: float,
+                      phase_ms: tuple) -> None:
+        """Account one executed batch and resolve its futures: crop each
+        row back to its native shape, build the response, set the result
+        (client callbacks run here) and finish any stream. ``phase_ms``
+        is (assembly, enqueue, wait for results) on the gateway clock."""
+        es = batch.entries
+        assembly_ms, dispatch_ms, wait_ms = phase_ms
         settle_t = self.clock()
         with self._stats_lock:
             m = self._m
@@ -1245,9 +1304,17 @@ class Gateway(GatewayBase):
             m.forwards.inc(nfe)
             m.real_rows.inc(len(es))
             m.padded_rows.inc(batch.bucket)
-            m.host_assembly_ms.observe((t1 - t0) * 1e3)
-            m.device_dispatch_ms.observe((t2 - t1) * 1e3)
+            m.host_assembly_ms.observe(assembly_ms)
+            m.device_dispatch_ms.observe(dispatch_ms)
+            m.device_wait_ms.observe(wait_ms)
             self._note_program(program)
+            # step i of the batch is needed by the rows whose served budget
+            # lies beyond i (a mixed batch's early exits ride along unneeded)
+            served = sorted(e.served for e in es)
+            prev = 0
+            for j, b in enumerate(served):
+                self._note_rows(len(served) - j, b - prev)
+                prev = b
             if es[0].native_shape is not None:
                 tier = es[0].shape_key[1]
                 self._note_tier(
@@ -1258,7 +1325,7 @@ class Gateway(GatewayBase):
                 m.completed.inc()
                 self._note_deadline(e, settle_t)
         rec = self.recorder
-        for e, row in zip(es, rows):
+        for e, row in zip(es, lat_rows):
             row = crop_row(row, e.native_shape)
             wait_ms = (dispatched - e.t_submit) * 1e3
             if rec:

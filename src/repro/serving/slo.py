@@ -13,7 +13,8 @@ gateways consult when a ``SLOConfig`` is attached:
 * **Admission control.** ``submit`` fast-rejects with ``AdmissionRejected``
   when the queue's MODELED service time cannot meet the deadline. The cost
   model is the registry's own observed dispatch-time histograms
-  (``device_dispatch_ms`` + ``host_assembly_ms`` means — see
+  (``device_dispatch_ms`` + ``device_wait_ms`` + ``host_assembly_ms``
+  means — see
   ``GatewayBase._dispatch_cost_ms``), so it calibrates itself from live
   traffic: no configuration, and on the fake clock it sees simulated
   milliseconds, making the overload bench deterministic.
