@@ -122,12 +122,16 @@ class AnytimeCarry(NamedTuple):
     x:    trajectory state after ``step`` update rules.
     step: number of velocity evaluations done so far (a static Python int —
           jit carry-stepping functions per (start, stop) pair, not on it).
+    rows: the rows the next leg advances (w distinct int32 indices into
+          the leading axis), or None for every row; see ``anytime_extend``.
+          The carry a leg returns names no rows.
     """
 
     x0: Array
     U: Array
     x: Array
     step: int
+    rows: Array | None = None
 
 
 def anytime_carry(params: AnytimeParams, budgets: Sequence[int],
@@ -162,7 +166,24 @@ def anytime_extend(params: AnytimeParams, budgets: Sequence[int],
     Costs exactly ``stop - carry.step`` velocity evaluations. ``update_fn``
     mirrors ``ns_sample(update_fn=...)`` (e.g. the Pallas ``ns_update``
     kernel); it receives the full fixed-width ``U`` with zero-masked weights.
+
+    A carry that names ``rows`` (w distinct indices into its leading axis)
+    advances those rows alone, at width w: ``u_fn`` sees only them (the
+    caller gives it their conditioning), their ``U`` and ``x`` are written
+    back into the full carry, the other rows keep what they held, and the
+    exits are w wide (exit row j is carry row ``rows[j]``). Rows are
+    independent through every update, so a row's result does not depend on
+    which rows ride beside it, up to how the backend rounds a matmul at
+    another width.
     """
+    rows = carry.rows
+    if rows is not None:
+        part = AnytimeCarry(x0=carry.x0[rows], U=carry.U[:, rows],
+                            x=carry.x[rows], step=carry.step)
+        part, outs = anytime_extend(params, budgets, u_fn, part, stop,
+                                    update_fn=update_fn)
+        return AnytimeCarry(x0=carry.x0, U=carry.U.at[:, rows].set(part.U),
+                            x=carry.x.at[rows].set(part.x), step=stop), outs
     budgets = sorted(budgets)
     n = budgets[-1]
     if not 0 <= carry.step < stop <= n:

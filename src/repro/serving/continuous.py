@@ -37,10 +37,19 @@ release/partial crops back to the entry's ``native_shape`` before the
 caller sees it (bit-identical to the direct sampler at the native shape).
 
 Samplers must speak the carry protocol on top of the budget protocol:
-``carry_start(batch, x0)`` and ``carry_extend(batch, carry, stop)``
-(``AnytimeFlowSampler`` jit-caches one program per (start, stop) leg).
-With ``mesh=`` the carry arrays are re-placed on the serving mesh after
-every join scatter (``sharded.carry_placer``).
+``carry_start(batch, x0)``, ``carry_extend(batch, carry, stop)`` and
+``carry_warm(batch, carry, stop)`` (``AnytimeFlowSampler`` jit-caches one
+program per (start, stop) leg and width). With ``mesh=``
+the carry arrays are re-placed on the serving mesh after every leg and
+join scatter (``sharded.carry_placer``).
+
+Leg width: the carry is always ``max_slots`` wide, but each leg runs over
+the live slots alone (the carry's ``rows``), padded with free
+slots to the join ladder's power of two, no narrower than an eighth of
+``max_slots`` or the mesh's data-axis size: a trajectory with 3 of 16
+slots live pays for 4 rows a step, not 16. The first trajectory of each
+shape warms every narrow (leg, width) program through ``carry_warm``, so
+traffic compiles none.
 """
 from __future__ import annotations
 
@@ -258,7 +267,7 @@ class ContinuousGateway(Gateway):
                  mesh=None, clock=None, key=None,
                  max_leg: Optional[int] = None, join_cost_cap: float = 0.5,
                  metrics=None, recorder=None, slo=None, tiers=None):
-        for method in ("carry_start", "carry_extend"):
+        for method in ("carry_start", "carry_extend", "carry_warm"):
             if not hasattr(sampler, method):
                 raise TypeError(
                     "continuous batching needs a resumable anytime sampler "
@@ -281,10 +290,19 @@ class ContinuousGateway(Gateway):
             slo_aware=slo is not None)
         self._traj: Optional[_Trajectory] = None
         self._place_carry = None
+        # the narrowest leg: an eighth of the slots, three rungs of the
+        # ladder below them (each narrower rung is one more program per
+        # leg for the first trajectory of a shape to warm, and a step over
+        # one row reads the same weights as over two), and the mesh's
+        # data-axis size
+        self._min_width = max(1, max_slots // 8)
         if mesh is not None:
             from repro.serving import sharded
 
             self._place_carry = sharded.carry_placer(mesh)
+            self._min_width = max(self._min_width,
+                                  sharded.data_axis_size(mesh))
+        self._warmed: set = set()   # shape keys whose narrow legs are warm
 
     # -- engine tick ---------------------------------------------------------
 
@@ -355,6 +373,37 @@ class ContinuousGateway(Gateway):
             return device_ms / completed * (ahead + 1)
         return super()._estimate_wait_ms(entry)
 
+    def _leg_width(self, live: int) -> int:
+        """Rows a leg dispatches for ``live`` occupied slots: the join
+        ladder's power of two, floored at ``_min_width``, capped at
+        ``max_slots``."""
+        slots = self.scheduler.max_slots
+        return self.scheduler.join_bucket(
+            min(max(live, self._min_width), slots))
+
+    def _warm_legs(self, traj: _Trajectory) -> None:
+        """Run once every narrow (leg, width) program a trajectory of this
+        shape can dispatch — the legs from step 0 along ``next_boundary``,
+        each width below ``max_slots`` — on the fresh carry, so no
+        compilation waits for traffic. Full-width legs are not narrowed
+        and warm as before."""
+        slots = self.scheduler.max_slots
+        widths = sorted({self._leg_width(n) for n in range(1, slots)}
+                        - {slots})
+        if not widths:
+            return
+        legs, step = [], 0
+        while (stop := self.scheduler.next_boundary(step)) is not None:
+            legs.append((step, stop))
+            step = stop
+        with profile_span("continuous.warm", programs=len(legs) * len(widths)):
+            cond = traj.cond()
+            for start, stop in legs:
+                carry = traj.carry._replace(step=start)
+                for w in widths:
+                    self.sampler.carry_warm(cond, carry._replace(
+                        rows=jnp.asarray(np.arange(w, dtype=np.int32))), stop)
+
     def _start_trajectory(self, starters: list, now: float) -> None:
         """Open a trajectory over ``starters`` (costs no forwards — the
         first leg runs on the next tick; waits end here, at admission)."""
@@ -375,6 +424,9 @@ class ContinuousGateway(Gateway):
             e.t_admit, e.join_step = now, 0
         traj.carry = carry
         self._traj = traj
+        if traj.shape_key not in self._warmed:
+            self._warm_legs(traj)     # a warm that raises is tried again
+            self._warmed.add(traj.shape_key)
         with self._stats_lock:
             self._m.trajectories.inc()
             self._m.host_assembly_ms.observe(assembly_ms)
@@ -393,14 +445,27 @@ class ContinuousGateway(Gateway):
         boundary = self.scheduler.next_boundary(step)
         assert boundary is not None, "trajectory ran past the top budget"
         active = traj.active()
+        width = self._leg_width(len(active))
+        # exit row j is slot rows[j]: the live slots and, as padding, free
+        # ones (their rows are stale; a join overwrites them whole); at
+        # full width, every slot in order, through the unnarrowed program
+        rows = sorted([si for si, _ in active]
+                      + traj.free_slots()[:width - len(active)])
+        carry = traj.carry
+        if width < self.scheduler.max_slots:
+            carry = carry._replace(rows=jnp.asarray(np.asarray(rows,
+                                                               np.int32)))
         with profile_span(f"continuous.leg.{step}-{boundary}",
-                          live=len(active), bucket=self.scheduler.max_slots):
+                          live=len(active), bucket=width):
             t0 = self.clock()   # gateway clock: fake-clock benches feed the
             #                     SLO cost model simulated dispatch times
-            carry, exits = self.sampler.carry_extend(traj.cond(), traj.carry,
+            carry, exits = self.sampler.carry_extend(traj.cond(), carry,
                                                      boundary)
+            if self._place_carry is not None:
+                carry = self._place_carry(carry)
             leg_ms = (self.clock() - t0) * 1e3
         traj.carry = carry
+        pos = {si: j for j, si in enumerate(rows)}
         # a max_leg-clipped stop is a control point, not an exit boundary:
         # nothing releases or joins there, but interleaved flushes can run
         is_exit = boundary in self.scheduler.boundaries
@@ -423,13 +488,12 @@ class ContinuousGateway(Gateway):
             m.legs.inc()
             m.forwards.inc(boundary - step)
             m.slot_steps_active.inc(len(active) * (boundary - step))
-            m.slot_steps_total.inc(
-                self.scheduler.max_slots * (boundary - step))
+            m.slot_steps_total.inc(width * (boundary - step))
             m.device_dispatch_ms.observe(leg_ms)
             if wait_ms is not None:
                 m.device_wait_ms.observe(wait_ms)
             self._note_rows(len(active), boundary - step)
-            self._note_program(f"leg/{step}-{boundary}")
+            self._note_program(f"leg/{step}-{boundary}-k{width}")
             if active and active[0][1].native_shape is not None:
                 # per-tier occupancy, weighted by leg steps (the slot-
                 # steps convention): native rows carried vs padded rows
@@ -439,17 +503,17 @@ class ContinuousGateway(Gateway):
                 self._note_tier(
                     tier,
                     steps * sum(e.native_shape[0] for _, e in active),
-                    steps * self.scheduler.max_slots * tier[0])
+                    steps * width * tier[0])
         if latents is not None:
             with profile_span(f"continuous.release.{boundary}",
                               rows=len(released)):
                 for si, e in streaming:
-                    e.sink.partial(crop_row(latents[si], e.native_shape),
+                    e.sink.partial(crop_row(latents[pos[si]], e.native_shape),
                                    boundary=boundary)
                 for si, e in released:
                     self._release(traj, si, e,
-                                  crop_row(latents[si], e.native_shape),
-                                  boundary, len(active))
+                                  crop_row(latents[pos[si]], e.native_shape),
+                                  boundary, len(active), width)
         if is_exit:
             with profile_span("continuous.plan"):
                 joiners = self.scheduler.plan_joins(
@@ -473,7 +537,7 @@ class ContinuousGateway(Gateway):
             self._traj = None
 
     def _release(self, traj: _Trajectory, si: int, e: _Entry, row,
-                 boundary: int, batch_real: int) -> None:
+                 boundary: int, batch_real: int, batch_padded: int) -> None:
         """Resolve one slot's future at its exit boundary and free the slot."""
         wait_ms = (e.t_admit - e.t_submit) * 1e3
         with self._stats_lock:
@@ -492,7 +556,7 @@ class ContinuousGateway(Gateway):
             "served_budget": e.served,
             "nfe_batch": boundary,
             "batch_real": batch_real,
-            "batch_padded": self.scheduler.max_slots,
+            "batch_padded": batch_padded,
             "mixed": False,
             "wait_ms": wait_ms,
             "continuous": True,

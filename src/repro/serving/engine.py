@@ -261,25 +261,46 @@ class AnytimeFlowSampler:
         carry's per-row columns fully determine the remaining trajectory,
         the same property makes exit boundaries free preemption points
         (``serving.slo.PausedCarry``).
-        """
-        key = (carry.step, stop)
-        fn = self._extends.get(key)
-        if fn is None:
-            start, step_stop = key
 
-            def _extend(params, batch, x0, U, x):
+        A carry that names ``rows`` (int32, w distinct slot indices) runs
+        the leg at width w on those slots alone, in the same program: it
+        gathers their ``x0``, ``U``, ``x`` and conditioning, writes ``U``
+        and ``x`` back into the full carry, and returns exits w wide (exit
+        row j is slot ``rows[j]``; ``anytime_extend``). One program per
+        (leg, w).
+        """
+        narrow = () if carry.rows is None else (carry.rows,)
+        U, x, exits = self._leg(carry.step, stop)(
+            self.params, batch, carry.x0, carry.U, carry.x, *narrow)
+        return anytime_mod.AnytimeCarry(x0=carry.x0, U=U, x=x,
+                                        step=stop), exits
+
+    def _leg(self, start: int, stop: int) -> Callable:
+        """The jitted program of leg ``start..stop`` (one per leg; jit
+        specialises it per width)."""
+        fn = self._extends.get((start, stop))
+        if fn is None:
+            def _extend(params, batch, x0, U, x, rows=None):
+                if rows is not None and batch is not None:
+                    batch = jax.tree.map(lambda a: a[rows], batch)
                 field = M.velocity_field(params, self.cfg, self.sched, batch,
                                          cfg_scale=self.cfg_scale)
-                c = anytime_mod.AnytimeCarry(x0=x0, U=U, x=x, step=start)
+                c = anytime_mod.AnytimeCarry(x0=x0, U=U, x=x, step=start,
+                                             rows=rows)
                 out, exits = anytime_mod.anytime_extend(
-                    self.anytime, self.budgets, field.fn, c, step_stop,
+                    self.anytime, self.budgets, field.fn, c, stop,
                     update_fn=self.update_fn)
                 return out.U, out.x, exits
 
-            fn = self._extends[key] = jax.jit(_extend)
-        U, x, exits = fn(self.params, batch, carry.x0, carry.U, carry.x)
-        return anytime_mod.AnytimeCarry(x0=carry.x0, U=U, x=x,
-                                        step=stop), exits
+            fn = self._extends[(start, stop)] = jax.jit(_extend)
+        return fn
+
+    def carry_warm(self, batch: Optional[dict],
+                   carry: anytime_mod.AnytimeCarry, stop: int) -> None:
+        """Compile the (leg, width) program of ``carry_extend`` before
+        traffic needs it, by running it once on ``carry`` and dropping
+        what it computes."""
+        self.carry_extend(batch, carry, stop)
 
     def nearest_tokens(self, latents: Array) -> Array:
         """Decode sampled latents to tokens by nearest latent embedding."""

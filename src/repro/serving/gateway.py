@@ -435,7 +435,7 @@ class GatewayStats:
     joins: int = 0             # requests admitted into in-flight trajectories
     join_forwards: int = 0     # forwards spent computing join prefixes
     slot_steps_active: int = 0  # occupied slot-steps across trajectory legs
-    slot_steps_total: int = 0   # max_slots * steps across trajectory legs
+    slot_steps_total: int = 0   # dispatched width * steps across legs
     # decode serving (zero under the flow gateways):
     tokens_out: int = 0        # generated tokens delivered to clients
     cancelled: int = 0         # sequences dropped on a cancelled future
@@ -471,7 +471,7 @@ METRIC_SCHEMA: tuple = (
     ("joins", "counter", "requests admitted into in-flight work"),
     ("join_forwards", "counter", "forwards spent computing join prefixes"),
     ("slot_steps_active", "counter", "occupied slot-steps across legs"),
-    ("slot_steps_total", "counter", "available slot-steps across legs"),
+    ("slot_steps_total", "counter", "slot-steps dispatched across legs"),
     ("tokens_out", "counter", "generated tokens delivered to clients"),
     ("cancelled", "counter", "sequences dropped on a cancelled future"),
     ("prefill_calls", "counter", "chunked-prefill engine invocations"),

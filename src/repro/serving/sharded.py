@@ -27,6 +27,12 @@ def _data_axes(mesh):
     return (axes if len(axes) > 1 else axes[0]), size
 
 
+def data_axis_size(mesh) -> int:
+    """Devices along the composed data axes: the fewest rows a batch
+    needs to stay split."""
+    return _data_axes(mesh)[1]
+
+
 def shard_params(params, cfg, mesh):
     """Place a backbone param pytree on ``mesh`` per the serving/training
     sharding rules; returns the (now sharded) pytree."""

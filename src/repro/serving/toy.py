@@ -115,6 +115,13 @@ class ToyAnytimeSampler:
     def carry_extend(self, batch, carry: AnytimeCarry, stop: int):
         return anytime_extend(self.theta, self.budgets, self._u, carry, stop)
 
+    def carry_warm(self, batch, carry: AnytimeCarry, stop: int) -> None:
+        """Run the leg once so the operations it builds at this width are
+        compiled before traffic; the accounting path (``jit=False``) spends
+        no forwards on it."""
+        if self._jit:
+            self.carry_extend(batch, carry, stop)
+
 
 class CountingToySampler(ToyAnytimeSampler):
     """Eager variant metering batch-level backbone forwards — the NFE

@@ -1,8 +1,10 @@
 """Continuous batching: trajectory slot admission/release at exit
 boundaries (fake clock), mid-flight joins with prefix forwards accounting,
 bit-identity of every continuously-batched sample vs the direct sampler,
-interleaved flushes for non-joinable requests, drain, and the carry
-protocol on the real smoke backbone."""
+legs narrowed to their live slots, interleaved flushes for non-joinable
+requests, drain, and the carry protocol on the real smoke backbone."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,7 +14,8 @@ from repro.core.anytime import init_anytime
 from repro.serving import AnytimeFlowSampler, ContinuousGateway, Request
 from repro.serving.continuous import ContinuousScheduler
 from repro.serving.gateway import _Entry
-from repro.serving.toy import CountingToySampler, FakeClock
+from repro.serving.slo import SLOConfig
+from repro.serving.toy import CountingToySampler, FakeClock, ToyAnytimeSampler
 from repro.solvers import SolverArtifact, SolverSpec
 
 BUDGETS = (2, 4, 8)
@@ -42,6 +45,17 @@ def _x0(i, shape=(2,)):
 def _direct(x0s, budget):
     """Reference samples from a FRESH sampler (same theta, same arithmetic)."""
     return CountingCarrySampler().sample_from(None, jnp.stack(x0s), budget)
+
+
+def _leg_widths(gw) -> dict:
+    """``{"<a>-<b>": [widths]}`` of the legs dispatched, from the
+    ``dispatches{program="leg/<a>-<b>-k<w>"}`` counters."""
+    out: dict = {}
+    for key, v in gw.metrics.snapshot().items():
+        m = re.fullmatch(r'dispatches\{program="leg/(\d+-\d+)-k(\d+)"\}', key)
+        if m:
+            out.setdefault(m.group(1), []).extend([int(m.group(2))] * v)
+    return {leg: sorted(ws) for leg, ws in out.items()}
 
 
 def _entry(uid, served, t=0.0):
@@ -116,19 +130,147 @@ def test_trajectory_releases_each_budget_at_its_boundary():
     assert s["trajectories"] == 1 and s["legs"] == 2 and s["joins"] == 0
 
 
-def test_continuous_samples_bit_identical_to_direct_sampler():
-    gw, sampler, clock = _gateway()
-    x0s = [_x0(i) for i in range(3)]
+@pytest.mark.parametrize("slots,starters,joiners,widths", [
+    # releases narrow the legs 4 -> 2 -> 1
+    (4, (2, 4, 8), (), {"0-2": [4], "2-4": [2], "4-8": [1]}),
+    # a join at 2 widens to the full carry, a release narrows again
+    (4, (4, 8), (8,), {"0-2": [2], "2-4": [4], "4-8": [2]}),
+    # one live row of 8 slots at width 1, two joiners, then a release
+    (8, (8,), (8, 4), {"0-2": [1], "2-4": [4], "4-8": [2]}),
+])
+def test_continuous_samples_bit_identical_to_direct_sampler(
+        slots, starters, joiners, widths):
+    """Every sample equals the direct sampler's, bit for bit, while the
+    legs run at the width of their live slots and joins and releases
+    change that width mid-trajectory."""
+    gw, sampler, clock = _gateway(max_slots=slots)
+    budgets = starters + joiners
+    x0s = [_x0(i) for i in range(len(budgets))]
     futs = [gw.submit(Request(budget=b, x0=x))
-            for b, x in zip((2, 4, 8), x0s)]
-    gw.drain()
-    for fut, b, x0 in zip(futs, (2, 4, 8), x0s):
+            for b, x in zip(starters, x0s)]
+    clock.advance(1.0)
+    assert gw.pump() == 1                       # trajectory opens
+    futs += [gw.submit(Request(budget=b, x0=x))
+             for b, x in zip(joiners, x0s[len(starters):])]
+    gw.drain()                                  # joiners enter at 2
+    for i, (fut, b, x0) in enumerate(zip(futs, budgets, x0s)):
         direct = _direct([x0], b)[0]
         np.testing.assert_array_equal(np.asarray(fut.result().latents),
                                       np.asarray(direct))
         meta = fut.result().meta
         assert meta["continuous"] and meta["served_budget"] == b
-        assert meta["join_step"] == 0
+        assert meta["join_step"] == (0 if i < len(starters) else 2)
+    assert _leg_widths(gw) == widths
+
+
+def test_leg_dispatches_the_power_of_two_of_its_live_slots():
+    """With 8 slots and 3 live rows the leg runs 4 rows: the dispatch
+    label, the slot-steps paid for and the response's padded size say
+    so, while the forwards and the real rows stay those of the leg."""
+    gw, sampler, clock = _gateway(max_slots=8)
+    futs = [gw.submit(Request(budget=2, x0=_x0(i))) for i in range(3)]
+    clock.advance(1.0)
+    gw.drain()
+    snap = gw.metrics.snapshot()
+    assert snap['dispatches{program="leg/0-2-k4"}'] == 1
+    assert _leg_widths(gw) == {"0-2": [4]}
+    assert snap["slot_steps_total"] == 4 * 2
+    assert snap["slot_steps_active"] == 3 * 2
+    assert snap['forwards_by_rows{rows="3"}'] == 2
+    assert sampler.forwards == 2
+    for f in futs:
+        meta = f.result().meta
+        assert meta["batch_real"] == 3 and meta["batch_padded"] == 4
+
+
+def test_preempted_and_streaming_slots_keep_their_bits_across_widths():
+    """A slot preempted at a full-width boundary resumes into a narrowed
+    leg, and a streaming slot's partials and final cross the same width
+    change: every payload equals the direct sampler's, bit for bit."""
+    clock = FakeClock()
+    gw = ContinuousGateway(CountingCarrySampler(), max_slots=4,
+                           max_wait_ms=10.0, clock=clock, slo=SLOConfig())
+    stream = gw.submit_stream(budget=8, x0=_x0(0))
+    low = gw.submit(Request(budget=8, x0=_x0(1)))
+    short = [gw.submit(Request(budget=2, x0=_x0(2 + i))) for i in range(2)]
+    assert gw.pump(force=True) == 1              # 4 slots: opens full
+    hot = [gw.submit(Request(budget=4, x0=_x0(4 + i), priority=1))
+           for i in range(3)]
+    gw.pump()     # leg 0-2 at 4: shorts exit, two hots join, one preempts
+    assert gw.stats()["preemptions"] == 1 and not low.done()
+    gw.pump()     # leg 2-4 at 4: hots exit, the victim resumes at 4
+    gw.pump()     # leg 4-8 at 2: the stream and the victim exit
+    assert _leg_widths(gw) == {"0-2": [4], "2-4": [4], "4-8": [2]}
+    for f, b, i in [(low, 8, 1), (short[0], 2, 2), (short[1], 2, 3),
+                    (hot[0], 4, 4), (hot[1], 4, 5), (hot[2], 4, 6)]:
+        np.testing.assert_array_equal(np.asarray(f.result(1).latents),
+                                      np.asarray(_direct([_x0(i)], b)[0]))
+    chunks = stream.chunks(timeout=1)
+    assert [c.meta.get("boundary") for c in chunks[:-1]] == [2, 4]
+    for c, b in zip(chunks, (2, 4, 8)):
+        payload = c.payload.latents if c.final else c.payload
+        np.testing.assert_array_equal(np.asarray(payload),
+                                      np.asarray(_direct([_x0(0)], b)[0]))
+
+
+def test_first_trajectory_warms_the_narrow_legs():
+    """The first trajectory of a shape runs every narrow (leg, width)
+    program once; trajectories of any live count afterwards compile
+    nothing more."""
+    gw, sampler, clock = _gateway(ToyAnytimeSampler(budgets=BUDGETS),
+                                  max_slots=8)
+
+    def trajectory(budgets):
+        futs = [gw.submit(Request(budget=b, x0=_x0(i)))
+                for i, b in enumerate(budgets)]
+        clock.advance(1.0)
+        while not all(f.done() for f in futs):
+            gw.pump(force=True)
+        return futs
+
+    trajectory((8,) * 8)                         # full width, then warm
+    warm = gw.metrics.snapshot()["compilations"]
+    for live in range(1, 8):
+        trajectory(((2, 4, 8) * 3)[:live])
+    assert gw.metrics.snapshot()["compilations"] == warm
+    assert {w for ws in _leg_widths(gw).values() for w in ws} == {1, 2, 4, 8}
+
+
+def test_a_warm_that_raises_is_tried_again():
+    """A shape counts as warm only once its warm-up has returned: a warm
+    that raises fails the starters, and the next trajectory of the shape
+    warms again and serves."""
+    class FlakyWarm(CountingCarrySampler):
+        warms = 0
+
+        def carry_warm(self, batch, carry, stop):
+            self.warms += 1
+            if self.warms == 1:
+                raise RuntimeError("warm boom")
+
+    gw, sampler, clock = _gateway(FlakyWarm())
+    first = gw.submit(Request(budget=8, x0=_x0(0)))
+    assert gw.pump(force=True) == 1
+    with pytest.raises(RuntimeError, match="warm boom"):
+        first.result(timeout=0)
+    second = gw.submit(Request(budget=8, x0=_x0(1)))
+    gw.drain()
+    np.testing.assert_array_equal(np.asarray(second.result(1).latents),
+                                  np.asarray(_direct([_x0(1)], 8)[0]))
+    assert sampler.warms > 1
+
+
+@pytest.mark.parametrize("missing", ["carry_start", "carry_extend",
+                                     "carry_warm"])
+def test_sampler_without_the_carry_protocol_is_refused(missing):
+    """A sampler lacking any carry method fails at construction, not by
+    silently serving legs some other way."""
+    methods = {m: (lambda self, *a: None)
+               for m in ("carry_start", "carry_extend", "carry_warm")
+               if m != missing}
+    sampler = type("Partial", (), {"budgets": BUDGETS, **methods})()
+    with pytest.raises(TypeError, match=missing):
+        ContinuousGateway(sampler, max_slots=4)
 
 
 def test_join_mid_flight_costs_at_most_budget_incremental_forwards():
@@ -221,13 +363,16 @@ def test_drain_completes_trajectory_and_queue():
 
 
 def test_slot_occupancy_accounting():
+    """Occupancy is live slot-steps over the slot-steps paid for: each
+    leg runs its live slots' power of two, not all ``max_slots``."""
     gw, sampler, clock = _gateway(max_slots=4)
     gw.submit(Request(budget=2, x0=_x0(0)))
     gw.submit(Request(budget=4, x0=_x0(1)))
     gw.drain()
     s = gw.stats()
-    # leg 0..2 with 2/4 slots active, leg 2..4 with 1/4 active
-    assert s["slot_occupancy"] == pytest.approx((2 * 2 + 1 * 2) / (4 * 4))
+    # leg 0..2: 2 live at width 2; leg 2..4: 1 live at width 1
+    assert s["slot_occupancy"] == pytest.approx((2 * 2 + 1 * 2)
+                                                / (2 * 2 + 1 * 2))
     assert s["legs"] == 2 and s["forwards"] == 4
 
 
@@ -448,26 +593,38 @@ def test_backbone_continuous_gateway_end_to_end(backbone):
 
 
 @pytest.mark.integration
-def test_backbone_sharded_continuous_matches_unsharded(backbone):
+@pytest.mark.parametrize("budgets", [(2, 4), (4,)])
+def test_backbone_sharded_continuous_matches_unsharded(backbone, budgets):
+    """On the host mesh a leg narrows no further than the data axes'
+    size, and the carry it leaves stays split along them; one live row
+    is the narrowest case."""
     from repro.launch.mesh import make_host_mesh
+    from repro.serving.sharded import data_axis_size
 
     cfg, batch, make_sampler = backbone
     ref_sampler = make_sampler()
     sampler = make_sampler()     # fresh: sharding re-places its params
     clock = FakeClock()
+    mesh = make_host_mesh()
     gw = ContinuousGateway(sampler, max_slots=2, max_wait_ms=10.0,
-                           mesh=make_host_mesh(), clock=clock)
-    toks = batch["tokens"][:2]
-    x0 = jax.random.normal(jax.random.PRNGKey(7), (2, 8, cfg.latent_dim))
-    futs = [gw.submit(Request(tokens=toks[i], budget=(2, 4)[i], x0=x0[i]))
-            for i in range(2)]
+                           mesh=mesh, clock=clock)
+    n = len(budgets)
+    toks = batch["tokens"][:n]
+    x0 = jax.random.normal(jax.random.PRNGKey(7), (n, 8, cfg.latent_dim))
+    futs = [gw.submit(Request(tokens=toks[i], budget=b, x0=x0[i]))
+            for i, b in enumerate(budgets)]
+    gw.pump(force=True)                          # opens
+    gw.pump()                                    # leg 0..2
+    width = max(gw.scheduler.join_bucket(n), data_axis_size(mesh))
+    assert _leg_widths(gw) == {"0-2": [min(width, 2)]}
+    if gw._traj is not None:
+        assert gw._traj.carry.x.sharding.spec[0] == "data"
     gw.drain()
-    ref2 = ref_sampler.sample_from({"tokens": toks[:1]}, x0[:1], 2)
-    ref4 = ref_sampler.sample_from({"tokens": toks[1:]}, x0[1:], 4)
-    np.testing.assert_allclose(np.asarray(futs[0].result().latents),
-                               np.asarray(ref2[0]), atol=1e-5, rtol=1e-5)
-    np.testing.assert_allclose(np.asarray(futs[1].result().latents),
-                               np.asarray(ref4[0]), atol=1e-5, rtol=1e-5)
+    for i, b in enumerate(budgets):
+        ref = ref_sampler.sample_from({"tokens": toks[i:i + 1]},
+                                      x0[i:i + 1], b)
+        np.testing.assert_allclose(np.asarray(futs[i].result().latents),
+                                   np.asarray(ref[0]), atol=1e-5, rtol=1e-5)
 
 
 def test_plan_start_shape_groups_independent():

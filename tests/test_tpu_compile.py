@@ -25,7 +25,8 @@ from repro.kernels.flash_attention.paged_attention import paged_attention
 from repro.kernels.ns_update.ns_update import ns_update_nd
 from repro.kernels.ns_update.ops import make_update_fn
 from repro.models import model as M
-from repro.serving.engine import DecodeEngine, FlowSampler
+from repro.core.anytime import init_anytime
+from repro.serving.engine import AnytimeFlowSampler, DecodeEngine, FlowSampler
 from repro.solvers.registry import build_ns
 
 HBM_BYTES = 16 * 2**30      # one v5e chip
@@ -121,6 +122,29 @@ def test_flow_step_compiles_at_yi6b_widths(one_chip):
         {"tokens": _spec(one_chip, (8, 16), jnp.int32)},
         _spec(one_chip, (8, 16, cfg.latent_dim))).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("width", [4, 16])
+def test_anytime_leg_compiles_at_yi6b_widths(one_chip, width):
+    """A guided trajectory leg 4..8 over a 16-slot carry of 64 latent
+    positions, narrowed to ``width`` live rows (16: the full program)."""
+    cfg = _yi6b_depth2()
+    budgets = (4, 8, 16)
+    sampler = AnytimeFlowSampler(params=None, cfg=cfg,
+                                 sched=get_scheduler("fm_ot"),
+                                 anytime=init_anytime(None, budgets),
+                                 budgets=budgets, cfg_scale=1.5)
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    slots, S, L = 16, 64, cfg.latent_dim
+    rows = () if width == slots else (_spec(one_chip, (width,), jnp.int32),)
+    compiled = sampler._leg(4, 8).lower(
+        _specs(one_chip, params),
+        {"tokens": _spec(one_chip, (slots, S), jnp.int32)},
+        _spec(one_chip, (slots, S, L)), _spec(one_chip, (16, slots, S, L)),
+        _spec(one_chip, (slots, S, L)), *rows).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
 
 
 def test_paged_decode_step_compiles_at_yi6b_widths(one_chip, monkeypatch):
