@@ -6,12 +6,13 @@
 For each seed, one run of the cell as ``run.py`` makes it (weights from
 the seed, warm-up, a window at the cell's own load, the timed path's
 outputs), all seeds in one process; then the comparison reads the program
-against the float32 reference and, on the same sampled requests, the
-control one step below the configuration's bfloat16: the reference
-computed at fp8 (e4m3, scaled per output channel and per row), which gives
-each limit its upper reading. One JSON line per seed on stdout. The limits of ``traffic/<mix>.json`` are set
-from these readings (``PERF.md`` gives them); the benchmark's own runs
-never compute the control.
+against the float32 reference over the block stack the configuration
+names and, on the same sampled requests, the control one step below the
+configuration's bfloat16: that reference computed at fp8 (e4m3, scaled
+per output channel and per row), which gives each limit its upper
+reading. One JSON line per seed on stdout. The limits of
+``traffic/<mix>.json`` are set from these readings (``PERF.md`` gives
+them); the benchmark's own runs never compute the control.
 """
 import json
 import os

@@ -16,6 +16,6 @@ def read(run):
         return None
     req, srv = run["mix"]["requests"], run["mix"]["server"]
     flops = flow_row_steps(run) * work.flow_request_flops(
-        run["model"].c, 1, req["positions"], srv["cfg_scale"] != 0.0)
+        run["model"], 1, req["positions"], srv["cfg_scale"] != 0.0)
     return share(flops / run["peaks"]["bf16_flop_per_s"],
                  tr["programs"]["flow"])

@@ -9,7 +9,7 @@ from bench.readers import in_window
 def read(run):
     req, srv = run["mix"]["requests"], run["mix"]["server"]
     guided = srv["cfg_scale"] != 0.0
-    flops = sum(work.flow_request_flops(run["model"].c, r["spec"]["budget"],
+    flops = sum(work.flow_request_flops(run["model"], r["spec"]["budget"],
                                         req["positions"], guided)
                 for r in run["records"]
                 if r.get("ok") and in_window(run, r["t_done"]))

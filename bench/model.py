@@ -5,10 +5,37 @@ source, every key ``reduced`` or ``corrected`` from it with the published
 value, what is ``assumed``, the deployment it stands for, and ``smoke``
 sizes for the CPU rehearsal. ``arch`` names the program's own
 configuration that these sizes replace field by field.
+
+A key of ``model`` whose ``ModelConfig`` field is itself a dataclass
+(``moe``: ``MoEConfig``, ``ssm``: ``SSMConfig``, ``frontend``:
+``FrontendStub``) holds a dict of that sub-configuration's sizes, such as
+``"moe": {"num_experts": 8}``: it replaces the program's own
+sub-configuration field by field, or builds one where the program has
+none. ``smoke`` merges into ``model`` one level deep, so a smoke
+sub-configuration states only the sub-keys it shrinks.
+
+``"blocks": "<stack>"`` names the block stack of the plain reference and
+of the work counts: the module ``blocks/<stack>.py`` (``Model.stack``,
+with ``blocks``, ``position_params``, ``causal_attn_flops`` and
+``matrix_bytes``). A name with no module is an error, never a default.
 """
 from __future__ import annotations
 
 import dataclasses
+import importlib
+import typing
+
+
+def stack_module(name: str):
+    """The module ``blocks/<name>.py``."""
+    mod = f"bench.blocks.{name}"
+    try:
+        return importlib.import_module(mod)
+    except ModuleNotFoundError as exc:
+        if exc.name != mod:
+            raise
+        raise ValueError(f"no block stack {name!r} "
+                         f"(bench/blocks/{name}.py)") from None
 
 
 class Model:
@@ -16,7 +43,10 @@ class Model:
         self.doc = doc
         self.c = dict(doc["model"])
         if smoke:
-            self.c.update(doc["smoke"])
+            for k, v in doc["smoke"].items():
+                self.c[k] = ({**(self.c.get(k) or {}), **v}
+                             if isinstance(v, dict) else v)
+        self.stack = stack_module(doc["blocks"])
 
     @property
     def name(self) -> str:
@@ -28,9 +58,31 @@ class Model:
         from repro.configs import get_config
 
         base = get_config(self.doc["arch"])
-        fields = {f.name for f in dataclasses.fields(base)}
-        unknown = sorted(set(self.c) - fields)
-        if unknown:
-            raise KeyError(f"{self.name}: keys unknown to the program's "
-                           f"ModelConfig: {unknown}")
-        return dataclasses.replace(base, **self.c)
+        return _replace(base, self.c, self.name)
+
+
+def _refuse_unknown(cls, keys, where: str) -> None:
+    unknown = sorted(set(keys) - {f.name for f in dataclasses.fields(cls)})
+    if unknown:
+        raise KeyError(f"{where}: keys unknown to the program's "
+                       f"{cls.__name__}: {unknown}")
+
+
+def _replace(base, sizes: dict, where: str):
+    """``base`` (a dataclass) with ``sizes`` set field by field; a dict
+    for a field whose type is a dataclass builds that sub-configuration
+    the same way, from the base's own where it has one."""
+    _refuse_unknown(type(base), sizes, where)
+    hints = typing.get_type_hints(type(base))
+    out = {}
+    for k, v in sizes.items():
+        sub = [t for t in typing.get_args(hints[k]) or (hints[k],)
+               if dataclasses.is_dataclass(t)]
+        if sub and isinstance(v, dict):
+            if getattr(base, k) is None:
+                _refuse_unknown(sub[0], v, f"{where}.{k}")
+                v = sub[0](**v)
+            else:
+                v = _replace(getattr(base, k), v, f"{where}.{k}")
+        out[k] = v
+    return dataclasses.replace(base, **out)

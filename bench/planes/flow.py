@@ -17,7 +17,8 @@ gateway stops and its state is freed.
 
 ``correct``: a sample of the window's completed requests drawn from the
 seed, ``check.per_budget`` of every served budget, is compared with the
-plain reference (``bench.reference``, float32): the widest relative L2
+plain reference (``bench.reference`` over the block stack the
+configuration names, ``Model.stack``; float32): the widest relative L2
 error of each budget's latents against that budget's limit.
 """
 from __future__ import annotations
@@ -155,23 +156,24 @@ def _sample(ctx, window, results) -> list[int]:
 
 def _check(ctx, params, window, results):
     """Relative L2 error of each sampled served latent against the
-    float32 reference; the widest of each served budget is held to that
-    budget's limit (an early exit averages the velocities it has seen,
-    the top budget chains 16 evaluations, so rounding grows about five
-    times from budget 8 to 16; one limit would let the lower budgets
-    drift unseen). With ``ctx['control']`` (``bench/control.py``, never
-    a benchmark run) the same samples are also computed by the control
-    (the reference at fp8) and its errors from the float32 reference are
-    read beside."""
+    float32 reference over the configuration's block stack; the widest
+    of each served budget is held to that budget's limit (an early exit
+    averages the velocities it has seen, the top budget chains 16
+    evaluations, so rounding grows about five times from budget 8 to 16;
+    one limit would let the lower budgets drift unseen). With
+    ``ctx['control']`` (``bench/control.py``, never a benchmark run) the
+    same samples are also computed by the control (the reference at fp8)
+    and its errors from the float32 reference are read beside."""
     import jax
     import jax.numpy as jnp
 
-    srv, c = ctx["mix"]["server"], ctx["model"].c
+    srv, model = ctx["mix"]["server"], ctx["model"]
     chosen = _sample(ctx, window, results)
     budgets = sorted(srv["budgets"])
     modes = ("f32", "fp8") if ctx.get("control") else ("f32",)
     steps = {m: jax.jit(lambda p, t, x, tok, m=m: reference.guided(
-        p, c, t, x, tok, srv["cfg_scale"], m)) for m in modes}
+        p, model.stack, model.c, t, x, tok, srv["cfg_scale"], m))
+        for m in modes}
     errs: dict = {}                     # (reading, budget) -> [errors]
     for b in budgets:
         group = [i for i in chosen if window[i]["spec"]["budget"] == b]

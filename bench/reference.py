@@ -1,17 +1,20 @@
 """Plain reference of what the cells serve, written from the published
 descriptions, imports nothing of the program.
 
-A llama-style dense decoder (RMSNorm, rotary positions on the two halves
-of each head, grouped-query attention, SwiGLU) as the flow backbone of the
-paper's velocity field: latents in through a linear projection plus the
-conditioning token embeddings, a sinusoidal time embedding through a
-two-layer SiLU MLP, causal attention, a linear projection out;
+What every flow configuration shares: a decoder stack as the backbone of
+the paper's velocity field, with latents in through a linear projection
+plus the conditioning token embeddings, a sinusoidal time embedding
+through a two-layer SiLU MLP, the stack, a linear projection out;
 classifier-free guidance mixes the conditional and the unconditional
-field.
+field; the anytime nested Euler solver integrates it. The stack itself
+(blocks and final norm) is the one the configuration names:
+``"blocks": "<stack>"`` in ``configs/<name>.json`` is the module
+``blocks/<stack>.py`` (``Model.stack``), built on ``linear``, ``rmsnorm``
+and ``rotary`` here.
 
 ``mode`` picks the arithmetic:
   * ``"f32"``: every matrix product in float32 at ``highest`` precision,
-    weights upcast layer by layer inside the scan, so at most one
+    weights upcast layer by layer inside the stack's scan, so at most one
     layer's float32 copy is alive — the reference proper;
   * ``"fp8"``: the control, one step below the configuration's bfloat16:
     weights and inputs cast to float8 e4m3, scaled per output channel and
@@ -67,43 +70,6 @@ def rotary(x, pos, theta):
     return jnp.concatenate([a * cos - b * sin, a * sin + b * cos], -1)
 
 
-def attention(p, x, pos, c, mode):
-    """Causal GQA over one batch of sequences x (B, S, d)."""
-    B, S, _ = x.shape
-    H, KV, hd = c["n_heads"], c["n_kv_heads"], c["head_dim"]
-    q = linear(x, p["wq"], mode).reshape(B, S, H, hd)
-    k = linear(x, p["wk"], mode).reshape(B, S, KV, hd)
-    v = linear(x, p["wv"], mode).reshape(B, S, KV, hd)
-    q = rotary(q, pos, c["rope_theta"])
-    k = rotary(k, pos, c["rope_theta"])
-    # query head h reads key/value head h // (H / KV)
-    k = jnp.repeat(k, H // KV, axis=2)
-    v = jnp.repeat(v, H // KV, axis=2)
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k, precision=HI) / math.sqrt(hd)
-    causal = pos[:, None] >= pos[None, :]
-    s = jnp.where(causal[None, None], s, -jnp.inf)
-    o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v,
-                   precision=HI)
-    return linear(o.reshape(B, S, H * hd), p["wo"], mode)
-
-
-def blocks(params, c, h, pos, mode):
-    """The stacked blocks (scanned) and the final norm."""
-    eps = c["norm_eps"]
-
-    def body(h, lp):
-        a = attention(lp["attn"], rmsnorm(h, lp["norm1"], eps), pos, c, mode)
-        h = h + a
-        m = rmsnorm(h, lp["norm2"], eps)
-        g = linear(m, lp["mlp"]["w_gate"], mode)
-        u = linear(m, lp["mlp"]["w_up"], mode)
-        h = h + linear(jax.nn.silu(g) * u, lp["mlp"]["w_down"], mode)
-        return h, None
-
-    h, _ = jax.lax.scan(body, h.astype(jnp.float32), params["layers"])
-    return rmsnorm(h, params["final_norm"], eps)
-
-
 # -- flow ---------------------------------------------------------------------
 
 
@@ -116,9 +82,10 @@ def time_features(t, d):
     return jnp.concatenate([jnp.cos(ang), jnp.sin(ang)])
 
 
-def velocity(params, c, t, x, tokens, mode):
+def velocity(params, stack, c, t, x, tokens, mode):
     """u_t(x) for latents x (B, S, latent); ``tokens`` (B, S) or None for
-    the unconditional field."""
+    the unconditional field; ``stack`` is the configuration's block
+    module (``Model.stack``)."""
     f = params["flow"]
     h = linear(x, f["proj_in"], mode)
     if tokens is not None:
@@ -126,15 +93,16 @@ def velocity(params, c, t, x, tokens, mode):
     e = time_features(t, c["d_model"])[None]
     e = linear(jax.nn.silu(linear(e, f["time_w1"], mode)), f["time_w2"], mode)
     h = h + e[:, None, :]
-    h = blocks(params, c, h, jnp.arange(x.shape[1]), mode)
+    h = stack.blocks(params, c, h, jnp.arange(x.shape[1]), mode)
     return linear(h, f["proj_out"], mode)
 
 
-def guided(params, c, t, x, tokens, scale, mode):
-    uc = velocity(params, c, t, x, tokens, mode)
+def guided(params, stack, c, t, x, tokens, scale, mode):
+    uc = velocity(params, stack, c, t, x, tokens, mode)
     if scale == 0.0:
         return uc
-    return (1.0 + scale) * uc - scale * velocity(params, c, t, x, None, mode)
+    return (1.0 + scale) * uc - scale * velocity(params, stack, c, t, x, None,
+                                                 mode)
 
 
 def nested_euler(budgets):

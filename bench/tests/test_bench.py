@@ -1,8 +1,8 @@
 """CPU tests of the benchmark's yardstick: the trace reduction, the work
-counts, the traffic generator, the contract of ``BENCHMARK.json``, one
-``--smoke`` run of each plane, and the comparison that decides
-``correct`` (a timed path broken underneath must read false; the control
-must read far above the program)."""
+counts, the configuration files and the weights, the traffic generator,
+the contract of ``BENCHMARK.json``, one ``--smoke`` run of each cell, and
+the comparison that decides ``correct`` (a timed path broken underneath
+must read false; the control must read far above the program)."""
 import contextlib
 import dataclasses
 import io
@@ -14,6 +14,8 @@ import numpy as np
 import pytest
 
 from bench import loadgen, trace, work
+from bench.blocks import gqa_swiglu
+from bench.model import Model
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
@@ -23,6 +25,13 @@ TESTDATA = os.path.join(ROOT, "bench", "testdata")
 def _json(rel):
     with open(os.path.join(ROOT, rel)) as f:
         return json.load(f)
+
+
+def _yi6b(smoke=False):
+    return Model(_json("bench/configs/yi-6b.json"), smoke=smoke)
+
+
+CELLS = [w["name"] for w in _json("BENCHMARK.json")["workloads"]]
 
 
 # -- trace reduction -----------------------------------------------------------
@@ -123,33 +132,171 @@ def test_trace_reduce_recorded_chip_trace():
 
 
 def test_work_hand_counts_yi6b():
-    c = _json("bench/configs/yi-6b.json")["model"]
+    model = _yi6b()
+    c = model.c
+    assert model.stack is gqa_swiglu
     # attention 4096*4096*2 + 2*4096*512, MLP 3*4096*11008
-    assert work.attn_params(c) == 37_748_736
-    assert work.mlp_params(c) == 135_266_304
-    assert 32 * work.layer_params(c) == 5_536_481_280
+    assert gqa_swiglu.attn_params(c) == 37_748_736
+    assert gqa_swiglu.mlp_params(c) == 135_266_304
+    assert 32 * gqa_swiglu.layer_params(c) == 5_536_481_280
+    assert gqa_swiglu.position_params(c) == 5_536_481_280
     # per position: 2 * (blocks + proj_in + proj_out) + causal attention
     per_pos = 2 * (5_536_481_280 + 2 * 64 * 4096)
     attn = 4 * 32 * 32 * 128 * (64 * 65 / 2)
-    assert work.flow_forward_flops(c, 1, 64) == pytest.approx(
+    assert gqa_swiglu.causal_attn_flops(c, 64, 0) == attn
+    assert work.flow_forward_flops(model, 1, 64) == pytest.approx(
         64 * per_pos + attn + 4 * 4096 ** 2)
     # a budget-8 guided sample: 8 steps x 2 forwards, no time-MLP per row
-    assert work.flow_request_flops(c, 8, 64, True) == pytest.approx(
+    assert work.flow_request_flops(model, 8, 64, True) == pytest.approx(
         16 * (64 * per_pos + attn))
     # unguided: one forward a step
-    assert work.flow_request_flops(c, 8, 64, False) == pytest.approx(
+    assert work.flow_request_flops(model, 8, 64, False) == pytest.approx(
         8 * (64 * per_pos + attn))
+
+
+@pytest.mark.parametrize("tokens", [1, 128, 2048])
+def test_work_weight_floor_yi6b_whatever_the_tokens(tokens):
+    """A dense stack reads every matrix once whatever the step holds:
+    11.07 GB of bf16 blocks and latent projections for yi-6b."""
+    model = _yi6b()
+    assert gqa_swiglu.matrix_bytes(model.c, tokens) == 2 * 5_536_481_280
+    assert work.flow_weight_bytes(model, tokens) == 11_074_011_136
 
 
 def test_least_time_never_above_what_the_chip_can_do():
     """Shares built on these counts stay under 100% for a step the chip
     ran at its measured best: a guided yi-6b step over 16 x 64 rows took
     132 ms on a v5e (PERF.md), and the least time must be below it."""
-    c = _json("bench/configs/yi-6b.json")["model"]
     peaks = _json("bench/peaks.json")["kinds"]["TPU v5 lite"]
-    least = 16 * work.flow_request_flops(c, 1, 64, True) / peaks[
+    least = 16 * work.flow_request_flops(_yi6b(), 1, 64, True) / peaks[
         "bf16_flop_per_s"]
     assert 0.05 < least < 0.132
+
+
+# -- configuration files and weights ---------------------------------------
+
+# the program's qwen3-moe-30b-a3b with 8 of its 128 experts, in a file
+MOE_DOC = {"name": "qwen3-moe-test", "arch": "qwen3-moe-30b-a3b",
+           "blocks": "gqa_swiglu",
+           "model": {"n_layers": 4, "moe": {"num_experts": 8}},
+           "smoke": {"n_layers": 2, "d_model": 128, "n_heads": 4,
+                     "n_kv_heads": 2, "head_dim": 32, "vocab": 256,
+                     "moe": {"d_expert": 64}, "dtype": "float32"}}
+PINS = os.path.join(TESTDATA, "yi6b_smoke_pins.json")
+
+
+def test_config_nested_moe_builds_the_program_sub_config():
+    """A nested ``moe`` replaces the program's ``MoEConfig`` field by
+    field: the file's sizes, the program's other fields."""
+    from repro.configs import MoEConfig, get_config
+
+    cfg = Model(MOE_DOC).program_config()
+    base = get_config("qwen3-moe-30b-a3b")
+    assert isinstance(cfg.moe, MoEConfig)
+    assert cfg.moe == dataclasses.replace(base.moe, num_experts=8)
+    assert cfg.n_layers == 4 and cfg.d_model == base.d_model
+    # where the program has none, the file's sizes build one
+    doc = {**MOE_DOC, "arch": "yi-6b",
+           "model": {"moe": {"num_experts": 8, "top_k": 2, "d_expert": 64}}}
+    assert Model(doc).program_config().moe == MoEConfig(8, 2, 64)
+    # the harness's readers still see plain dicts
+    assert Model(MOE_DOC).c["moe"] == {"num_experts": 8}
+
+
+def test_config_smoke_overlay_keeps_the_sub_keys_it_does_not_state():
+    model = Model(MOE_DOC, smoke=True)
+    assert model.c["moe"] == {"num_experts": 8, "d_expert": 64}
+    cfg = model.program_config()
+    assert (cfg.moe.num_experts, cfg.moe.d_expert, cfg.moe.top_k) == (8, 64, 8)
+    assert cfg.n_layers == 2 and cfg.dtype == "float32"
+
+
+@pytest.mark.parametrize("model,where", [
+    ({"n_layers": 4, "n_expert": 8}, "ModelConfig"),
+    ({"moe": {"num_experts": 8, "n_shared": 2}}, "MoEConfig")])
+def test_config_unknown_key_is_refused(model, where):
+    with pytest.raises(KeyError, match=where):
+        Model({**MOE_DOC, "model": model}).program_config()
+
+
+def test_config_unknown_block_stack_is_refused():
+    """A stack with no module under ``bench/blocks/`` is refused, never
+    run as another."""
+    with pytest.raises(ValueError, match="mla_experts"):
+        Model({**MOE_DOC, "blocks": "mla_experts"})
+
+
+def test_weights_draw_a_bias_leaf():
+    import jax
+    import jax.numpy as jnp
+
+    from bench import weights
+
+    sd = jax.ShapeDtypeStruct
+    p = weights.make_params({"final_norm": sd((64,), jnp.float32),
+                             "router": {"w": sd((32, 64), jnp.float32),
+                                        "e_score_correction_bias":
+                                            sd((4096,), jnp.float32)}}, 3)
+    bias = np.asarray(p["router"]["e_score_correction_bias"])
+    assert abs(bias.mean()) < 0.01 and bias.std() == pytest.approx(0.1,
+                                                                   rel=0.05)
+    assert np.asarray(p["final_norm"]).mean() == pytest.approx(1.0, abs=0.05)
+    assert np.asarray(p["router"]["w"]).std() == pytest.approx(
+        32 ** -0.5, rel=0.1)
+
+
+def _yi6b_smoke_params(seed):
+    import jax
+
+    from bench import weights
+    from repro.models import model as M
+
+    cfg = _yi6b(smoke=True).program_config()
+    shapes = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    return weights.make_params(shapes, seed)
+
+
+def test_weights_yi6b_smoke_tree_is_pinned():
+    """yi-6b's arrays, leaf by leaf, as the weights were drawn before
+    bias leaves had a rule of their own."""
+    import hashlib
+
+    import jax
+
+    pins = _json(PINS)
+    flat = jax.tree_util.tree_flatten_with_path(
+        _yi6b_smoke_params(pins["seed"]))[0]
+    got = {jax.tree_util.keystr(path): hashlib.sha256(
+        np.asarray(v).tobytes()).hexdigest() for path, v in flat}
+    assert got == pins["weights_sha256"]
+
+
+def test_reference_gqa_swiglu_is_pinned():
+    """The reference's guided budget-4 sample at yi-6b's smoke sizes, in
+    float32 and in the fp8 control, equals exactly what the reference
+    gave before its block stack moved into ``bench/blocks/``
+    (``testdata/yi6b_smoke_reference.npz``); jitted as the check jits it."""
+    import jax
+
+    from bench import reference
+
+    pins = _json(PINS)
+    model = _yi6b(smoke=True)
+    c = model.c
+    params = _yi6b_smoke_params(pins["seed"])
+    rng = np.random.default_rng(pins["seed"])
+    x0 = jax.numpy.asarray(rng.standard_normal((2, 8, c["latent_dim"]),
+                                               np.float32))
+    tok = jax.numpy.asarray(rng.integers(0, c["vocab"], (2, 8)).astype(
+        np.int32))
+    want = np.load(os.path.join(TESTDATA, "yi6b_smoke_reference.npz"))
+    for mode in ("f32", "fp8"):
+        step = jax.jit(lambda p, t, x, tk, mode=mode: reference.guided(
+            p, model.stack, c, t, x, tk, 1.5, mode))
+        got = reference.flow_sample(lambda t, x: step(params, t, x, tok),
+                                    (4, 8, 16), 4, x0)
+        np.testing.assert_array_equal(np.asarray(got), want[mode])
 
 
 # -- traffic -----------------------------------------------------------------------
@@ -309,9 +456,9 @@ def _run(*argv):
     return json.loads(buf.getvalue().strip().splitlines()[-1])
 
 
-def test_smoke_run_names_the_cpu():
-    out = _run("--workload", "yi6b.flow.mixed", "--smoke",
-               "--seed", "2147483659")
+@pytest.mark.parametrize("cell", CELLS)
+def test_smoke_run_names_the_cpu(cell):
+    out = _run("--workload", cell, "--smoke", "--seed", "2147483659")
     assert list(out)[-1] == "checks"
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0 and out["metrics"] == {}
@@ -411,8 +558,8 @@ def _fake_run(cell, traced):
     return run
 
 
-def test_readers_read_their_cell_and_stay_in_range():
-    cell = "yi6b.flow.mixed"
+@pytest.mark.parametrize("cell", CELLS)
+def test_readers_read_their_cell_and_stay_in_range(cell):
     b = _json("BENCHMARK.json")
     for group, traced in (("end_to_end", False), ("per_layer", True)):
         run = _fake_run(cell, traced)
@@ -430,6 +577,15 @@ def test_readers_read_their_cell_and_stay_in_range():
             assert _reader(m["name"])(untraced) is None, m["name"]
 
 
+@pytest.mark.parametrize("name", ["flow.mfu", "flow.backbone_roofline",
+                                  "flow.backbone_bound"])
+def test_work_readers_yi6b_pinned(name):
+    """The readers built on the work counts read, on the synthetic run,
+    exactly what they read when the counts were yi-6b's own formulas."""
+    run = _fake_run("yi6b.flow.mixed", True)
+    assert _reader(name)(run) == _json(PINS)["readers"][name]
+
+
 def test_flow_row_steps_count_requests_inside_the_window():
     from bench.readers import flow_row_steps
 
@@ -445,7 +601,6 @@ def test_backbone_roofline_counts_the_requests_not_the_padding():
     over the same device time read the same share whatever the dispatched
     batches held, and the least time of the requests' steps, at peak,
     over the device time."""
-    c = _json("bench/configs/yi-6b.json")["model"]
     peaks = _json("bench/peaks.json")["kinds"]["TPU v5 lite"]
     read = _reader("flow.backbone_roofline")
     run = _fake_run("yi6b.flow.mixed", True)
@@ -453,6 +608,6 @@ def test_backbone_roofline_counts_the_requests_not_the_padding():
     run["trace"]["spans"] = [("continuous.leg.0-16", 1.0, 1.1),
                              ("gateway.dispatch.b4/k16", 2.0, 2.1)]
     assert read(run) == v
-    least = 31 * work.flow_request_flops(c, 1, 64, True) / peaks[
+    least = 31 * work.flow_request_flops(_yi6b(), 1, 64, True) / peaks[
         "bf16_flop_per_s"]
     assert v == pytest.approx(100 * least / 6.0)

@@ -8,6 +8,7 @@ import pytest
 
 from bench import run as bench_run
 from bench import spans, work
+from bench.blocks import gqa_swiglu
 from bench.model import Model
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
@@ -59,11 +60,11 @@ def test_host_busy_and_tick_max_read_the_self_times():
 
 def test_backbone_bound_weight_floor_decides_at_one_row_not_eight():
     read = bench_run.reader("flow.backbone_bound")
-    c = _json("bench/configs/yi-6b.json")["model"]
+    model = Model(_json("bench/configs/yi-6b.json"))
     peaks = _json("bench/peaks.json")["kinds"]["TPU v5 lite"]
-    row_s = work.flow_request_flops(c, 1, 64, True) / peaks[
+    row_s = work.flow_request_flops(model, 1, 64, True) / peaks[
         "bf16_flop_per_s"]
-    weight_s = (2 * (32 * work.layer_params(c) + 2 * 64 * 4096)
+    weight_s = (2 * (32 * gqa_swiglu.layer_params(model.c) + 2 * 64 * 4096)
                 / peaks["hbm_bytes_per_s"])
     # yi-6b: about 11.1 GB of matrices, 13.5 ms a step; a row 7.2 ms
     assert weight_s == pytest.approx(0.0135, rel=0.01)

@@ -6,7 +6,9 @@ the device, in the dtype it is served in. The reference reads these same
 arrays by their names, so it never takes a number the program made.
 
 Matrices are N(0, 1/fan_in); embedding tables N(0, 1); norm scales
-1 + 0.1 N(0, 1), so a path that drops or misplaces a norm scale shows.
+1 + 0.1 N(0, 1), so a path that drops or misplaces a norm scale shows;
+any other 1-D leaf is a bias (a router's per-expert score correction,
+say), 0.1 N(0, 1), so a path that drops it shows.
 """
 from __future__ import annotations
 
@@ -27,6 +29,8 @@ def _leaf(key, name: str, shape, dtype):
         x = 1.0 + 0.1 * jax.random.normal(key, shape, jnp.float32)
     elif "embed" in name:
         x = jax.random.normal(key, shape, jnp.float32)
+    elif len(shape) == 1:
+        x = 0.1 * jax.random.normal(key, shape, jnp.float32)
     else:
         x = jax.random.normal(key, shape, jnp.float32) * shape[-2] ** -0.5
     return x.astype(dtype)
