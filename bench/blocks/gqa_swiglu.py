@@ -6,7 +6,8 @@ RMSNorm after the last block.
 
 ``blocks`` is the reference's stack (``reference.velocity`` runs it);
 ``position_params``, ``causal_attn_flops`` and ``matrix_bytes`` are the
-least work of the same stack, which ``work.py`` and the readers take.
+least work of the same stack, which ``work.py`` and the readers take;
+``KERNELS`` names the program's device kernels for the trace reduction.
 ``c`` is a configuration dict (``n_layers``, ``d_model``, ``n_heads``,
 ``n_kv_heads``, ``head_dim``, ``d_ff``, ``rope_theta``, ``norm_eps``).
 """
@@ -19,6 +20,10 @@ import jax.numpy as jnp
 
 from bench.reference import HI, linear, rmsnorm, rotary
 from bench.work import param_bytes
+
+# device kernels of the stack, by substrings of their operations' names:
+# the program runs yi-6b's blocks as XLA fusions, so none
+KERNELS: dict = {}
 
 
 def attention(p, x, pos, c, mode):

@@ -14,10 +14,14 @@ sub-configuration field by field, or builds one where the program has
 none. ``smoke`` merges into ``model`` one level deep, so a smoke
 sub-configuration states only the sub-keys it shrinks.
 
-``"blocks": "<stack>"`` names the block stack of the plain reference and
-of the work counts: the module ``blocks/<stack>.py`` (``Model.stack``,
-with ``blocks``, ``position_params``, ``causal_attn_flops`` and
-``matrix_bytes``). A name with no module is an error, never a default.
+``"blocks": "<stack>"`` names the block stack of the plain reference, of
+the work counts and of the device kernels: the module
+``blocks/<stack>.py`` (``Model.stack``, with ``blocks``,
+``position_params``, ``causal_attn_flops``, ``matrix_bytes`` and
+``KERNELS``, a dict from a kernel's name to substrings of its device
+operations' names, which the trace reduction credits: ``{}`` for a stack
+that runs no kernel of its own). A name with no module, or a module
+without one of these, is an error, never a default.
 """
 from __future__ import annotations
 
@@ -26,16 +30,27 @@ import importlib
 import typing
 
 
+# what a block module states
+STACK_NAMES = ("blocks", "position_params", "causal_attn_flops",
+               "matrix_bytes", "KERNELS")
+
+
 def stack_module(name: str):
-    """The module ``blocks/<name>.py``."""
+    """The module ``blocks/<name>.py``, which states every name of
+    ``STACK_NAMES``."""
     mod = f"bench.blocks.{name}"
     try:
-        return importlib.import_module(mod)
+        stack = importlib.import_module(mod)
     except ModuleNotFoundError as exc:
         if exc.name != mod:
             raise
         raise ValueError(f"no block stack {name!r} "
                          f"(bench/blocks/{name}.py)") from None
+    missing = [n for n in STACK_NAMES if not hasattr(stack, n)]
+    if missing:
+        raise ValueError(f"block stack {name!r} (bench/blocks/{name}.py) "
+                         f"states no {', '.join(missing)}")
+    return stack
 
 
 class Model:

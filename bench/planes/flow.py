@@ -4,10 +4,13 @@ Set-up makes the weights from the seed, builds the ``AnytimeFlowSampler``
 over the undistilled anytime solver (``core.anytime.init_anytime``: a
 budget-m request costs m guided forwards whatever the coefficients are,
 and distilling would add a minute to every run) and one
-``ContinuousGateway``. It warms every program the traffic can reach —
-trajectory legs, join prefixes and flush batches at every padded bucket,
-and the small eager scatters of a join — then sends warm-up traffic from
-its own seed stream through the same gateway.
+``ContinuousGateway``. It warms every program the gateway can dispatch
+for the server the mix names (``_warm_programs``: flush batches and
+trajectory legs, and with more than one budget join prefixes, merged
+flushes and the small eager scatters of a join), then sends warm-up
+traffic from its own seed stream through the same gateway. The trace is
+reduced with the kernels the configuration's block module names
+(``KERNELS``).
 
 The window: open-loop arrivals from ``loadgen`` (entry ``submit``), each
 timed from its scheduled send until its latents are on the host (the
@@ -29,31 +32,58 @@ from bench import harness, loadgen, reference
 from bench.harness import log, now
 
 
+# the server settings the plane builds its sampler and gateway from
+SERVER_KEYS = {"budgets", "cfg_scale", "scheduler", "max_slots",
+               "max_wait_ms", "mixed_budget_policy"}
+
+
 def _warm_programs(sampler, srv, S: int, L: int):
-    """Run once every program the gateway can dispatch for this traffic
-    (``S`` positions of width ``L``), at every padded batch size it can
-    choose."""
+    """Run once every program the gateway can dispatch for this server
+    and traffic (``S`` positions of width ``L``), at every padded batch
+    size it can choose: flush batches at every bucket and the trajectory
+    legs from step 0. With more than one budget also the join prefixes to
+    each exit boundary below the top, the merged flush (unless the policy
+    is ``never``) and the small eager scatters of a join; legs run at full
+    width here, and the gateway's first trajectory warms their narrow
+    widths during the warm-up traffic. With one budget there is no
+    boundary to join at and nothing to merge, and the one leg is warmed
+    here at every width of the ladder, so the window compiles nothing
+    whatever the warm-up traffic. A server with no budget, no slot, or a
+    setting the plane does not pass on is refused."""
     import jax
     import jax.numpy as jnp
 
+    unknown = sorted(set(srv) - SERVER_KEYS)
+    if unknown or not srv["budgets"] or srv["max_slots"] < 1:
+        raise ValueError(f"flow: cannot warm the server {srv}: budgets "
+                         f"and max_slots >= 1 needed, unknown {unknown}")
     budgets = sorted(srv["budgets"])
+    joins = budgets[:-1]                        # exit boundaries to join at
     slots = srv["max_slots"]
     prefix = None
     for k in harness.pow2_upto(slots):
         cond = {"tokens": jnp.zeros((k, S), jnp.int32)}
         x = jnp.zeros((k, S, L), jnp.float32)
-        for b in budgets[:-1]:                  # join prefixes 0..b
+        outs = []
+        for b in joins:                         # join prefixes 0..b
             c = sampler.carry_start(cond, x)
             prefix, _ = sampler.carry_extend(cond, c, b)
             for i in range(k):                  # per-slot carry columns
                 prefix.x0[i], prefix.U[:, i], prefix.x[i]
+            outs.append(prefix.x)
         for m in budgets:                       # flush batches
-            sampler.sample_from(cond, x, m)
-        if srv["mixed_budget_policy"] != "never":
+            outs.append(sampler.sample_from(cond, x, m))
+        if joins and srv["mixed_budget_policy"] != "never":
             sampler.sample_all_from(cond, x)
-        jax.block_until_ready(prefix.x)
+        jax.block_until_ready(outs)
     cond = {"tokens": jnp.zeros((slots, S), jnp.int32)}
     carry = sampler.carry_start(cond, jnp.zeros((slots, S, L), jnp.float32))
+    if not joins:                               # the one leg, every width
+        for w in harness.pow2_upto(slots):
+            c = carry if w == slots else carry._replace(
+                rows=jnp.asarray(np.arange(w, dtype=np.int32)))
+            jax.block_until_ready(sampler.carry_extend(cond, c, budgets[0]))
+        return
     for b in budgets:                           # trajectory legs
         carry, _ = sampler.carry_extend(cond, carry, b)
     for j in range(1, slots + 1):               # join scatters of j rows
@@ -132,13 +162,13 @@ def run(ctx) -> dict:
     served = {i: np.asarray(r["future"].result().latents)
               for i, r in enumerate(window) if r.get("ok")}
     del gw, sampler
-    return harness.result(ctx, meas, PROGRAMS, KERNELS,
+    return harness.result(ctx, meas, PROGRAMS, model.stack.KERNELS,
                           lambda: _check(ctx, params, window, served))
 
 
-# programs and kernels the trace readers look for (stable jit names)
+# programs the trace readers look for (stable jit names); the kernels are
+# the ones the configuration's block module names (``KERNELS``)
 PROGRAMS = {"flow": ("jit__extend", "jit__sample")}
-KERNELS: dict = {}
 
 
 def _sample(ctx, window, results) -> list[int]:

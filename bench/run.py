@@ -7,10 +7,12 @@
 The cell (``BENCHMARK.json`` ``workloads``) names a configuration and a
 traffic mix. Everything is found by name: the configuration's file, the
 block stack ``bench/blocks/<stack>.py`` that it names (the reference's
-blocks and their work counts), the mix ``bench/traffic/<traffic>.json``,
-the plane ``bench/planes/<plane>.py`` that the mix names, and one reader
-``bench/metrics/<metric>.py`` per metric. Adding a cell, a configuration,
-a block stack, a mix or a metric adds files and entries; it edits none.
+blocks, their work counts and the device kernels the trace reduction
+credits), the mix ``bench/traffic/<traffic>.json`` (one budget or many,
+guided or not), the plane ``bench/planes/<plane>.py`` that the mix names,
+and one reader ``bench/metrics/<metric>.py`` per metric. Adding a cell, a
+configuration, a block stack, a mix or a metric adds files and entries; it
+edits none.
 
 One process per run: the weights are made from the seed on the device,
 the cell's programs are warmed up, the window is measured from the client
