@@ -12,6 +12,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models.layers import apply_rope, dense_init, rms_norm
 
@@ -124,6 +125,13 @@ def _chunked_attend(q: Array, k: Array, v: Array, positions: Array,
     return jnp.moveaxis(outs, 0, 1).reshape(B, L, KV, G, hd)
 
 
+def _project(x: Array, w: Array) -> Array:
+    """``x @ w`` with its output pinned row-major, so that the dot reads
+    ``w`` in the layout it is stored in (see ``attention_forward``)."""
+    y = x @ w
+    return with_layout_constraint(y, Layout(tuple(range(y.ndim))))
+
+
 def attention_forward(
     p: dict,
     x: Array,                    # (B, L, d)
@@ -139,6 +147,9 @@ def attention_forward(
 ) -> Array:
     """Full-sequence attention (training / prefill).
 
+    q/k/v are pinned row-major so the RoPE/score layout (B, heads, L, hd)
+    relayouts the activations, never the stacked ``wq``/``wk``/``wv``.
+
     Under an installed sharding context (repro.distributed.context) this
     optionally runs sequence-parallel (query positions sharded on ``model``
     — required when head counts don't divide the tensor axis) and/or
@@ -148,9 +159,9 @@ def attention_forward(
 
     B, L, _ = x.shape
     G = n_heads // n_kv
-    q = _split_heads(x @ p["wq"], n_heads, head_dim)
-    k = _split_heads(x @ p["wk"], n_kv, head_dim)
-    v = _split_heads(x @ p["wv"], n_kv, head_dim)
+    q = _split_heads(_project(x, p["wq"]), n_heads, head_dim)
+    k = _split_heads(_project(x, p["wk"]), n_kv, head_dim)
+    v = _split_heads(_project(x, p["wv"]), n_kv, head_dim)
     if "q_norm" in p:
         q = rms_norm(q, p["q_norm"], norm_eps)
         k = rms_norm(k, p["k_norm"], norm_eps)

@@ -195,6 +195,10 @@ class AnytimeFlowSampler:
 
     def sample_from(self, batch: dict, x0: Array, budget: int) -> Array:
         """Integrate given noise ``x0`` at exactly ``budget`` NFE."""
+        return self._at_budget(budget)(self.params, batch, x0)
+
+    def _at_budget(self, budget: int) -> Callable:
+        """The jitted program of ``budget`` (one per budget)."""
         fn = self._per_budget.get(budget)
         if fn is None:
             ns = self.ns_at_budget(budget)   # raises on unserved budgets
@@ -206,7 +210,7 @@ class AnytimeFlowSampler:
                                            update_fn=self.update_fn)
 
             fn = self._per_budget[budget] = jax.jit(_sample)
-        return fn(self.params, batch, x0)
+        return fn
 
     def sample(self, batch: dict, key: Array, budget: int,
                strict: bool = False) -> Array:

@@ -179,3 +179,63 @@ def test_training_reduces_cfm_loss():
     _, losses = train("yi-6b", smoke=True, steps=30, batch=8, seq=16,
                       lr=3e-3, log=lambda *_: None)
     assert np.mean(losses[-5:]) < np.mean(losses[:5]) * 0.9, losses[:3] + losses[-3:]
+
+
+def _unpinned(monkeypatch):
+    """The projections as plain ``x @ w``, with no output layout pin."""
+    from repro.models import attention
+    monkeypatch.setattr(attention, "_project", lambda x, w: x @ w)
+
+
+def _gqa_cfg(qk_norm, dtype="bfloat16"):
+    cfg = get_config("yi-6b", smoke=True)
+    assert cfg.n_heads > cfg.n_kv_heads > 1
+    return dataclasses.replace(cfg, qk_norm=qk_norm, dtype=dtype)
+
+
+@pytest.mark.parametrize("head", ["flow_velocity", "lm_forward"])
+@pytest.mark.parametrize("qk_norm", [False, True])
+def test_pinned_projections_bit_identical_to_unpinned(monkeypatch, head,
+                                                      qk_norm):
+    """A layout pin moves data, not arithmetic: same bits out."""
+    from repro.models import model as M
+    from repro.models.transformer import flow_velocity, lm_forward
+    cfg = _gqa_cfg(qk_norm)
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (3, 16), 0, cfg.vocab)
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 16, cfg.latent_dim))
+    t = jnp.linspace(0.1, 0.9, 3)
+    if head == "flow_velocity":
+        def run(p):
+            return flow_velocity(p, cfg, t, x, tokens)
+    else:
+        def run(p):
+            return lm_forward(p, cfg, tokens)
+    pinned = jax.jit(run)(params)
+    _unpinned(monkeypatch)
+    plain = jax.jit(lambda p: run(p))(params)   # new function: a new trace
+    np.testing.assert_array_equal(np.asarray(pinned), np.asarray(plain))
+
+
+def test_pinned_projections_cfm_grad_bit_identical_to_unpinned(monkeypatch):
+    """The pin's transpose pins the cotangent the same way: same gradient.
+
+    In float32 weights. With bf16 weights the CPU's weight-gradient dots see
+    other operand layouts and round a few elements one or two bf16 ulps
+    apart; the bf16 forward is bit-identical (the test above)."""
+    from repro.core.schedulers import get_scheduler
+    from repro.models import model as M
+    cfg = _gqa_cfg(qk_norm=True, dtype="float32")
+    params = M.init_params(jax.random.PRNGKey(0), cfg)
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0,
+                                          cfg.vocab)}
+    sched = get_scheduler("fm_ot")
+
+    def grad(p):
+        return jax.grad(M.cfm_loss)(p, cfg, batch, jax.random.PRNGKey(3),
+                                    sched)
+    pinned = jax.jit(grad)(params)
+    _unpinned(monkeypatch)
+    plain = jax.jit(lambda p: grad(p))(params)  # new function: a new trace
+    for a, b in zip(jax.tree.leaves(pinned), jax.tree.leaves(plain)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -11,6 +11,8 @@ only one process at a time may load the TPU library, and every xdist worker
 imports this file.
 """
 import dataclasses
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -164,3 +166,85 @@ def test_paged_decode_step_compiles_at_yi6b_widths(one_chip, monkeypatch):
         _specs(one_chip, params), _spec(one_chip, (4,), jnp.int32),
         _specs(one_chip, state), _spec(one_chip, (4,), jnp.bool_)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def _weight_relayouts(hlo: str, layers) -> tuple[list[str], list[str]]:
+    """Instructions of ``hlo`` that copy a whole stacked layer weight, and
+    dynamic-slice fusions that yield one layer's slice of one (in bf16)."""
+    weights = [w for w in jax.tree.leaves(layers) if w.ndim == 3]
+    stacks = {"bf16[%s]" % ",".join(map(str, w.shape)) for w in weights}
+    per_layer = {math.prod(w.shape[1:]) for w in weights}
+    copies, slices = [], []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*%(\S+) = (bf16\[([\d,]*)\])\S* (\S+)\(", line)
+        if not m:
+            continue
+        name, shape, dims, op = m.groups()
+        if op == "copy" and shape in stacks:
+            copies.append(line.strip())
+        if (op == "fusion" and "dynamic-slice" in name
+                and math.prod(int(d) for d in dims.split(",")) in per_layer):
+            slices.append(line.strip())
+    return copies, slices
+
+
+@pytest.mark.parametrize("program", ["flush-8x32", "flush-guided-16x64",
+                                     "leg-0-4-8x32"])
+def test_flow_program_reads_stacked_weights_in_place(one_chip, program):
+    """The flow programs of both benchmark cells at yi-6b widths read the
+    stacked layer weights as stored: no per-call relayout of a whole stack
+    and no per-layer weight slice in the layer scan (the attention
+    projections pin their outputs, ``models.attention._project``)."""
+    cfg = _yi6b_depth2()
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    guided = program == "flush-guided-16x64"
+    budgets = (4, 8, 16) if guided else (4,)
+    sampler = AnytimeFlowSampler(params=None, cfg=cfg,
+                                 sched=get_scheduler("fm_ot"),
+                                 anytime=init_anytime(None, budgets),
+                                 budgets=budgets,
+                                 cfg_scale=1.5 if guided else 0.0)
+    B, S = (16, 64) if guided else (8, 32)
+    L = cfg.latent_dim
+    args = (_specs(one_chip, params),
+            {"tokens": _spec(one_chip, (B, S), jnp.int32)},
+            _spec(one_chip, (B, S, L)))
+    if program.startswith("leg"):
+        fn = sampler._leg(0, 4)
+        args += (_spec(one_chip, (max(budgets), B, S, L)),
+                 _spec(one_chip, (B, S, L)))
+    else:
+        fn = sampler._at_budget(4)
+    compiled = fn.lower(*args).compile()
+    copies, slices = _weight_relayouts(compiled.as_text(), params["layers"])
+    assert not copies, copies
+    assert not slices, slices
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def test_pinned_prefill_no_worse_than_unpinned(one_chip, monkeypatch):
+    """At a 4,096-token prefill, past the token count where relaying the
+    activations outweighs relaying the weights, the pinned projections
+    still cost no more temp memory than plain ``x @ w`` and relayout no
+    weight stack: the pin needs no switch on the token count."""
+    from repro.models import attention
+    from repro.models.transformer import lm_forward
+
+    cfg = _yi6b_depth2()
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    args = (_specs(one_chip, params), _spec(one_chip, (1, 4096), jnp.int32))
+
+    def temp_and_copies():
+        compiled = jax.jit(
+            lambda p, t: lm_forward(p, cfg, t, last_only=True)
+        ).lower(*args).compile()
+        copies, _ = _weight_relayouts(compiled.as_text(), params["layers"])
+        return compiled.memory_analysis().temp_size_in_bytes, copies
+
+    pinned, pinned_copies = temp_and_copies()
+    monkeypatch.setattr(attention, "_project", lambda x, w: x @ w)
+    plain, _ = temp_and_copies()
+    assert not pinned_copies, pinned_copies
+    assert pinned <= plain
