@@ -23,11 +23,6 @@ ARCH = "whisper-medium"
 SEQ, BATCH = 16, 24
 NFES = [8, 16]
 BASELINES = solver_names(family="generic", baseline=True)  # euler, midpoint
-# serving mix (continuous_bench multimodal scenario): audio clips arrive
-# at VARIABLE lengths up to this workload's SEQ — infill requests trim
-# the tail — so the live gateway sees near-shapes that only a tier
-# ladder can batch together
-REQUEST_LENGTHS = (SEQ - 6, SEQ - 3, SEQ)
 
 
 def run(train_steps: int = 200, bns_iters: int = 300, log=print):

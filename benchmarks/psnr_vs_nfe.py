@@ -9,8 +9,6 @@ network capacity, is what this figure measures.
 """
 from __future__ import annotations
 
-import time
-
 import jax
 import jax.numpy as jnp
 
@@ -41,16 +39,14 @@ def run(iterations: int = 3000, lr: float = 1e-3, log=print) -> list[dict]:
                 row[name] = SolverSpec(name, nfe).sampler(field).psnr(val)
             cfg = BNSTrainConfig(lr=lr, iterations=iterations, val_every=100,
                                  batch_size=64)
-            t0 = time.time()
             row["bns"] = SolverSpec("midpoint", nfe, mode="bns") \
                 .distill(field, train, val, cfg).val_psnr
-            row["bns_train_s"] = round(time.time() - t0, 1)
             row["bst"] = SolverSpec("euler", nfe, mode="bst") \
                 .distill(field, train, val, cfg).val_psnr
             rows.append(row)
             log(f"{sname} NFE={nfe}: " + " ".join(
                 f"{k}={v:.2f}" for k, v in row.items()
-                if isinstance(v, float) and k != "bns_train_s"))
+                if isinstance(v, float)))
     return rows
 
 

@@ -28,10 +28,6 @@ ARCH = "yi-6b"
 SEQ, BATCH = 16, 32
 NFES = [8, 12]
 BASELINES = solver_names(family="generic", baseline=True)  # euler, midpoint
-# serving mix (continuous_bench multimodal scenario): image latents come
-# at this workload's fixed grid resolution — same tier as the longest
-# audio clips, so the two modalities share one slot pool under a ladder
-REQUEST_LENGTHS = (SEQ,)
 
 
 def build_field(params, cfg, batch, w):

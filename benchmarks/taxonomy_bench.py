@@ -1,10 +1,7 @@
 """Figure 3 / Theorem 3.2 benchmark: every solver family converts to NS
-parameters with numerically-exact trajectory agreement, plus Algorithm-1
-runtime per call (the sampling engine's inner loop cost).
+parameters with numerically-exact trajectory agreement.
 """
 from __future__ import annotations
-
-import time
 
 import jax
 import jax.numpy as jnp
@@ -13,12 +10,6 @@ from repro.core import ns_solver, schedulers, solvers, st_solvers, st_transform,
 from repro.core.bst_solver import bst_euler_program, identity_bst, materialize_bst
 from repro.core.exponential import ddim_program, dpm2m_program
 from repro.solvers import build_ns, get_solver
-
-# serving mix (continuous_bench multimodal scenario): the text workload's
-# requests are SHORT variable-length sequences of this bench's toy points
-# — half the flow SEQ and under, so they land on a lower tier rung than
-# the audio/image latents and fill the pool's short tier
-REQUEST_LENGTHS = (5, 7, 8)
 
 
 def run(log=print):
@@ -53,15 +44,9 @@ def run(log=print):
         sample = jax.jit(lambda x, p=ns: ns_solver.ns_sample(p, field.fn, x))
         out = sample(x0)
         err = float(jnp.max(jnp.abs(out - direct)))
-        out.block_until_ready()
-        t0 = time.time()
-        for _ in range(20):
-            sample(x0).block_until_ready()
-        us = (time.time() - t0) / 20 * 1e6
-        rows.append({"solver": name, "n": ns.n, "max_err": err,
-                     "alg1_us_per_call": us})
+        rows.append({"solver": name, "n": ns.n, "max_err": err})
         log(f"[{'PASS' if err < 1e-3 else 'FAIL'}] {name:16s} -> NS(n={ns.n}) "
-            f"max|direct - Alg.1| = {err:.2e}  ({us:.0f} us/call)")
+            f"max|direct - Alg.1| = {err:.2e}")
     return rows
 
 

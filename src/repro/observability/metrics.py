@@ -4,10 +4,9 @@ One ``MetricsRegistry`` per serving tier (``GatewayBase`` owns one;
 ``FleetGateway`` merges its hosts' snapshots).  Design constraints, in
 order:
 
-* **Deterministic.**  The fake-clock benches gate histogram bucket
-  counts and interpolated percentiles against committed baselines, so
-  every operation is exact integer/float arithmetic — no sampling, no
-  reservoir, no decay.
+* **Deterministic.**  The fake-clock tests assert histogram bucket
+  counts and interpolated percentiles exactly, so every operation is
+  exact integer/float arithmetic — no sampling, no reservoir, no decay.
 * **Mergeable.**  ``snapshot()`` returns plain dicts of numbers (and
   histogram dicts) that ``merge_snapshots`` can sum across hosts —
   the fleet-wide p95 is computed from the SUMMED buckets, which is
@@ -20,7 +19,7 @@ order:
 Histograms use fixed log-spaced bucket bounds (``DEFAULT_MS_BOUNDS``:
 quarter-millisecond lower edge, sqrt(2) growth) so two registries that
 never exchanged state still merge exactly, and percentile error is
-bounded by one bucket width — the property ``continuous_bench`` gates.
+bounded by one bucket width — ``tests/test_scheduling_sim.py`` asserts it.
 """
 from __future__ import annotations
 

@@ -57,8 +57,8 @@ boundaries (the victim resumes from its saved carry, bit-identical).
 chunks (decode) and terminates with the exact settled response. With
 ``slo=None`` (default) every planner degenerates to the legacy FIFO
 behavior byte-for-byte. See ``docs/ARCHITECTURE.md`` for the full
-walkthrough and ``benchmarks/overload_bench.py`` for the
-goodput-under-overload gate.
+walkthrough and ``tests/test_overload_sim.py`` for goodput under
+overload.
 
 Cutting across all five layers sits the **observability** plane
 (``repro.observability``): every tier emits into ONE ``MetricsRegistry``
@@ -158,7 +158,7 @@ Module map:
 ``tiers``   — shape-tier ladder: pad near-shapes to configured rungs at
               submit so one slot pool serves heterogeneous multi-modal
               traffic, crop back to the native shape at settle;
-``toy``     — protocol-complete toy sampler/engine for benchmarks + tests.
+``toy``     — protocol-complete toy sampler/engine for the tests.
 """
 from repro.serving.continuous import ContinuousGateway, ContinuousScheduler
 from repro.serving.decode import (

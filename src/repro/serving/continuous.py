@@ -457,7 +457,7 @@ class ContinuousGateway(Gateway):
                                                                np.int32)))
         with profile_span(f"continuous.leg.{step}-{boundary}",
                           live=len(active), bucket=width):
-            t0 = self.clock()   # gateway clock: fake-clock benches feed the
+            t0 = self.clock()   # gateway clock: fake-clock tests feed the
             #                     SLO cost model simulated dispatch times
             carry, exits = self.sampler.carry_extend(traj.cond(), carry,
                                                      boundary)

@@ -19,7 +19,7 @@ Continuous slot refill
   admitted into freed slots at the very next engine step — the batch never
   drains to empty before refilling (run-to-completion batching does, and
   pays ``max(lengths)`` wall-steps per wave; see ``refill=False`` and
-  ``benchmarks/decode_bench.py``).
+  ``tests/test_scheduling_sim.py``).
 * Rows are independent through the backbone and each row carries its own
   position, so a sequence admitted into a freed slot produces tokens
   BIT-IDENTICAL to decoding it alone (MoE: in the no-capacity-drop regime,
@@ -35,7 +35,7 @@ invocations instead of 100 decode-step ticks, and sequences mid-generation
 keep emitting every tick while long prompts stream in beside them. Chunk
 widths are bucketed to powers of two so a serving session compiles at most
 ``log2(prefill_chunk)`` prefill programs. ``prefill_chunk=0`` restores the
-legacy token-by-token teacher-forced feed (the decode benchmark's
+legacy token-by-token teacher-forced feed (the scheduling tests'
 comparison baseline). The prefill scan body is the same ``decode_apply``
 as ``step_slots``, so generated tokens are bit-identical either way.
 
@@ -142,7 +142,7 @@ class PageAllocator:
     point at it, so their in-flight writes inside the one compiled step
     program land harmlessly instead of corrupting reallocated pages. The
     allocator hands out pages 1..total-1; ``peak`` tracks the high-water
-    mark (the benchmark's resident-memory gauge)."""
+    mark (the resident-memory gauge the paged-KV test reads)."""
 
     def __init__(self, total_pages: int):
         if total_pages < 2:
@@ -228,12 +228,12 @@ class DecodeGateway(GatewayBase):
     consuming their prompts, then one write-masked decode step over the
     rows past them (``engine.step_slots``) and advance each active
     sequence. ``start()``/``drain()``/``shutdown()`` come from
-    ``GatewayBase``; the unit tests and ``benchmarks/decode_bench.py``
-    drive ``pump`` directly with a fake clock.
+    ``GatewayBase``; the tests drive ``pump`` directly with a fake
+    clock.
 
     ``refill=False`` degrades admission to run-to-completion batching (new
     sequences wait until EVERY slot is free) — the baseline the decode
-    benchmark gates continuous refill against. ``prefill_chunk=0`` degrades
+    tests hold continuous refill against. ``prefill_chunk=0`` degrades
     prefill to the legacy token-by-token teacher-forced feed.
 
     The engine only needs the slot protocol (``init_slot_state``,
@@ -385,7 +385,7 @@ class DecodeGateway(GatewayBase):
             if not active.any():
                 return did
             sampling = self._slot_sampling() if self._sampling_resident else None
-            t0 = self.clock()   # gateway clock: fake-clock benches feed the
+            t0 = self.clock()   # gateway clock: fake-clock tests feed the
             #                     SLO cost model simulated dispatch times
             try:
                 with profile_span(f"decode.step.k{self.max_slots}"):

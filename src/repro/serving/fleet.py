@@ -190,7 +190,7 @@ class WorkStealer:
         """Moves ``(victim, thief, n)`` for one steal round — a pure
         function of the load snapshot (hosts visited in sorted order, so
         the round is deterministic). ``thieves`` overrides idleness
-        detection (the fake-clock bench knows device busyness the load
+        detection (the fake-clock tests know device busyness the load
         snapshot cannot see)."""
         if self.max_steal < 1:
             return []
@@ -237,7 +237,7 @@ class FleetGateway:
     Registration calls ``GatewayBase.federate`` on each host, so build
     hosts fresh and submit only through the fleet.
 
-    Manual mode (tests/benchmarks): ``pump()`` ticks every host once plus
+    Manual mode (tests): ``pump()`` ticks every host once plus
     one steal round, on whatever fake clock the host gateways share.
     Threaded mode: ``start()`` runs each host's serve thread plus a fleet
     balancer thread running steal rounds. ``drain/stop/shutdown`` mirror

@@ -1248,7 +1248,7 @@ class Gateway(GatewayBase):
             # assemble on host: ONE device transfer per batch, not one eager
             # stack/slice op per request (those dominate at small budgets).
             # Timing runs on the GATEWAY clock (production: time.monotonic,
-            # same resolution as perf_counter) so fake-clock benches feed
+            # same resolution as perf_counter) so fake-clock tests feed
             # the SLO cost model simulated, deterministic dispatch times
             with profile_span("gateway.assemble", rows=rows,
                               bucket=batch.bucket):

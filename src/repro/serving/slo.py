@@ -7,7 +7,7 @@ gateways consult when a ``SLOConfig`` is attached:
 
 * **Deadlines.** ``Request.deadline_ms`` / ``DecodeRequest.deadline_ms``
   is a relative budget (ms from submit); the gateway stamps the absolute
-  deadline on its own clock, so fake-clock benches measure SLO attainment
+  deadline on its own clock, so fake-clock tests measure SLO attainment
   deterministically. Settling on time ticks ``goodput``; settling late
   (or being shed) ticks ``deadline_misses``.
 * **Admission control.** ``submit`` fast-rejects with ``AdmissionRejected``
@@ -17,7 +17,7 @@ gateways consult when a ``SLOConfig`` is attached:
   means — see
   ``GatewayBase._dispatch_cost_ms``), so it calibrates itself from live
   traffic: no configuration, and on the fake clock it sees simulated
-  milliseconds, making the overload bench deterministic.
+  milliseconds, making the overload tests deterministic.
 * **Shedding.** A queued entry whose deadline already passed is failed
   with ``DeadlineExceeded`` at the next pump instead of burning a slot —
   under overload the forwards saved go to requests that can still win.
@@ -33,7 +33,7 @@ gateways consult when a ``SLOConfig`` is attached:
   (the exit-boundary join invariant of ``core.anytime.anytime_extend``).
 
 Everything here is a pure function of (entries, clock, config) — the unit
-tests and ``benchmarks/overload_bench.py`` drive it with a fake clock.
+tests and ``tests/test_overload_sim.py`` drive it with a fake clock.
 """
 from __future__ import annotations
 
@@ -66,7 +66,7 @@ class DeadlineExceeded(RuntimeError):
 class SLOConfig:
     """Switchboard for the SLO behaviours. ``slo=None`` on a gateway is
     exact legacy FIFO (deadline metrics are still recorded — the overload
-    bench's baseline arm); ``slo=SLOConfig()`` turns everything on.
+    tests' baseline arm); ``slo=SLOConfig()`` turns everything on.
 
     ``slack_ms`` is a safety margin subtracted from every deadline before
     the admission/shedding comparison. ``default_cost_ms`` seeds the cost

@@ -27,10 +27,9 @@ any pointwise score model); a backbone that mixes positions (full
 attention without masking) would need a position mask to keep the
 guarantee, which is why tiering is strictly OPT-IN (``tiers=None``
 preserves today's exact-shape behaviour) and the invariant is asserted
-against the direct-sampler oracle in ``tests/test_tiers.py`` and the
-mixed-modality ``continuous_bench`` scenario.
+against the direct-sampler oracle in ``tests/test_tiers.py``.
 
-Samples with no position axis (``ndim < 2``, e.g. the toy benches' bare
+Samples with no position axis (``ndim < 2``, e.g. the toy tests' bare
 ``(d,)`` points) are never padded: each such shape is its own exact tier.
 Requests LONGER than the top rung are rejected at submit with
 ``TierOversize`` — silently serving them unpadded would fragment the pool

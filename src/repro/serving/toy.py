@@ -3,11 +3,11 @@
 One implementation of the budget protocol (``budgets``, ``resolve_budget``,
 ``sample_from``, ``sample_all_from``) AND the carry protocol
 (``carry_start``, ``carry_extend``) over the analytic two-moons velocity
-field, shared by the serving benchmarks and the gateway/continuous test
-suites so they all exercise the SAME sampler:
+field, shared by the gateway, continuous and scheduling test suites so
+they all exercise the SAME sampler:
 
-* ``jit=True`` (benchmark timing): per-budget programs compiled once and
-  cached, like ``AnytimeFlowSampler``.
+* ``jit=True``: per-budget programs compiled once and cached, like
+  ``AnytimeFlowSampler``.
 * ``jit=False`` (forward accounting / fake-clock simulation): everything
   runs eagerly through ``_u``, which calls the ``on_forward`` hook once per
   BATCH-LEVEL velocity evaluation — override it to count backbone forwards
@@ -43,7 +43,7 @@ Array = jax.Array
 
 class FakeClock:
     """Deterministic clock for gateway simulation: ``gateway.clock`` is any
-    zero-arg callable, so tests and benchmarks advance time explicitly (or
+    zero-arg callable, so tests advance time explicitly (or
     from an engine/sampler forward hook) instead of sleeping."""
 
     def __init__(self):
@@ -139,7 +139,7 @@ class CountingToySampler(ToyAnytimeSampler):
 class ToyDecodeEngine:
     """Slot-protocol toy engine for the decode gateway (``init_slot_state``,
     ``step_slots``, ``reset_slots`` — what ``DecodeGateway`` needs), shared
-    by ``benchmarks/decode_bench.py`` and the decode-gateway tests.
+    by the decode-gateway and scheduling tests.
 
     The "model" is a deterministic affine map over the vocabulary,
     ``next = (a * token + b + position) % vocab`` — row-independent like the
@@ -149,16 +149,16 @@ class ToyDecodeEngine:
     ``on_step`` hook (fake clock / wall-step counting) fires exactly once
     per engine INVOCATION (decode step or prefill call) with zero compile
     noise — ``prefill_slots`` consumes a whole chunk of prompt tokens per
-    row in ONE invocation, which is exactly the wall-step saving the decode
-    benchmark measures. Greedy only (``supports_sampling = False``).
+    row in ONE invocation, which is exactly the wall-step saving the
+    scheduling tests count. Greedy only (``supports_sampling = False``).
 
     ``page_size > 0`` makes the engine SPEAK the paged protocol (``paged``
     property, ``with_block_table`` no-op) without simulating page contents
     — the position-vector state is already O(1) per slot. The gateway's
     ``PageAllocator`` bookkeeping (reservation, head-of-line blocking,
     free-on-finish, peak tracking) then runs for real against the toy
-    workload, which is what the decode benchmark's resident-memory metric
-    measures.
+    workload, which is what the paged-KV scheduling test's resident-memory
+    check reads.
     """
 
     supports_sampling = False

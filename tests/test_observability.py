@@ -15,6 +15,7 @@ import urllib.request
 import jax
 import numpy as np
 import pytest
+from fakeclock_sim import SKEW16, ParallelFleet, replay, stream
 
 from repro.observability import (
     MetricsRegistry,
@@ -282,21 +283,10 @@ def test_counters_consistent_under_concurrent_hammer():
 # ---------------------------------------------------------------------------
 
 
-def _fleet_bench():
-    import os
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    if root not in sys.path:
-        sys.path.insert(0, root)
-    from benchmarks import fleet_bench
-    return fleet_bench
-
-
 def test_fleet_stats_equal_merge_of_host_registries():
-    fb = _fleet_bench()
-    events = fb.schedule("skew16", 24, 2.0, burst=8)
-    waits, rows, stats, snap = fb.simulate(events, None, 2.0, 8, 12.0)
+    events = stream(SKEW16, 24, inter_ms=2.0, burst=8)
+    run = replay(lambda clock, tick: ParallelFleet(clock), events)
+    stats, snap = run.stats, run.snapshot
     assert stats["completed"] == 24
     assert snap["wait_ms"]["count"] == 24     # merge sums host histograms
     assert stats["wait_p95_ms"] == snap["wait_ms"]["p95"]
@@ -312,12 +302,11 @@ def test_stolen_request_hop_chain_reconstructable_from_jsonl(tmp_path):
     the dispatch host differing from the routed home."""
     from repro.serving import WorkStealer
 
-    fb = _fleet_bench()
     rec = TraceRecorder()
-    events = fb.schedule("skew16", 48, 2.0, burst=8)
+    events = stream(SKEW16, 48, inter_ms=2.0, burst=8)
     stealer = WorkStealer(min_queue=8, max_steal=4)
-    waits, rows, stats, snap = fb.simulate(events, stealer, 2.0, 8, 12.0,
-                                           recorder=rec)
+    stats = replay(lambda clock, tick: ParallelFleet(clock, stealer, rec),
+                   events).stats
     assert stats["steals"] > 0
     path = tmp_path / "trace.jsonl"
     n = rec.export_jsonl(str(path))
