@@ -25,7 +25,6 @@ from repro.core import (
     anytime,
     bns,
     bst_solver,
-    cfg,
     exponential,
     ns_solver,
     parametrization,
@@ -39,7 +38,7 @@ from repro.core import (
 )
 
 __all__ = [
-    "anytime", "bns", "bst_solver", "cfg", "exponential", "ns_solver", "parametrization",
+    "anytime", "bns", "bst_solver", "exponential", "ns_solver", "parametrization",
     "rk45", "schedulers", "solvers", "st_solvers", "st_transform", "taxonomy",
     "toy",
 ]

@@ -210,10 +210,30 @@ def velocity(params: dict, cfg: ModelConfig, t: Array, x: Array,
                                      hidden_fn=_hidden_fn(cfg, batch, remat))
 
 
+# Families whose guided step runs both CFG branches in one backbone pass,
+# stacked on the batch axis: their branches differ only by the additive
+# token embedding, and their hidden function treats rows independently.
+# The others keep two passes: vlm's conditional branch prepends patch
+# embeddings (another sequence length), encdec's frames feed the encoder,
+# and moe's expert capacity couples a row to its batch mates.
+STACKED_CFG_FAMILIES = ("dense", "ssm", "hybrid")
+
+
+def passes_per_step(cfg: ModelConfig, cfg_scale: float) -> int:
+    """Backbone passes per NFE step of ``velocity_field``: 1 when guidance
+    is off or its two branches stack on the batch axis, 2 when they run
+    apart."""
+    if cfg_scale == 0.0 or cfg.family in STACKED_CFG_FAMILIES:
+        return 1
+    return 2
+
+
 def velocity_field(params: dict, cfg: ModelConfig, sched: Scheduler,
                    batch: Optional[dict] = None, *, cfg_scale: float = 0.0
                    ) -> VelocityField:
-    """Wrap the model for the BNS sampler, with classifier-free guidance.
+    """Wrap the model for the BNS sampler, with classifier-free guidance:
+    ``(1 + w) u_cond - w u_uncond``, both branches in one backbone pass
+    over 2B rows where the family allows it (``passes_per_step``).
 
     ``fn`` is a ``jax.tree_util.Partial`` whose leaves are ``params`` and
     ``batch``: a jit that takes the field's ``fn`` as an argument receives
@@ -227,10 +247,17 @@ def velocity_field(params: dict, cfg: ModelConfig, sched: Scheduler,
 
 def _guided_velocity(params: dict, batch: Optional[dict], t: Array, x: Array,
                      *, cfg: ModelConfig, cfg_scale: float) -> Array:
-    uc = velocity(params, cfg, t, x, batch)
     if cfg_scale == 0.0:
-        return uc
-    uu = velocity(params, cfg, t, x, None)
+        return velocity(params, cfg, t, x, batch)
+    if cfg.family in STACKED_CFG_FAMILIES:
+        # one pass over 2B rows: the conditioning covers the first B, the
+        # last B add exact zeros in its place (the unconditional branch)
+        t2 = jnp.concatenate([t, t]) if jnp.ndim(t) else t
+        u = velocity(params, cfg, t2, jnp.concatenate([x, x]), batch)
+        uc, uu = jnp.split(u, 2)
+    else:
+        uc = velocity(params, cfg, t, x, batch)
+        uu = velocity(params, cfg, t, x, None)
     return (1.0 + cfg_scale) * uc - cfg_scale * uu
 
 

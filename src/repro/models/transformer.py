@@ -233,11 +233,17 @@ def flow_velocity(params: dict, cfg: ModelConfig, t: Array, x: Array,
 
     Conditioning: token embeddings added to the input projection (class/text
     conditioning analog); ``cond_tokens=None`` is the unconditional branch
-    (CFG). ``hidden_fn`` lets non-dense families reuse this head."""
+    (CFG). ``cond_tokens`` may cover only the leading rows of ``x``: the
+    rows past them add zeros, so they are unconditional (the CFG branches
+    stacked on the batch axis). ``hidden_fn`` lets non-dense families
+    reuse this head."""
     f = params["flow"]
     h = x.astype(f["proj_in"].dtype) @ f["proj_in"]
     if cond_tokens is not None:
-        h = h + params["embed"][cond_tokens]
+        e = params["embed"][cond_tokens]
+        if e.shape[0] < h.shape[0]:
+            e = jnp.pad(e, ((0, h.shape[0] - e.shape[0]), (0, 0), (0, 0)))
+        h = h + e
     temb = timestep_embedding(t, cfg.d_model).astype(h.dtype)
     temb = jax.nn.silu(temb @ f["time_w1"]) @ f["time_w2"]
     h = h + temb[:, None, :] if temb.ndim == 2 else h + temb[None, None, :]

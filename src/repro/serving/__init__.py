@@ -122,6 +122,11 @@ Metric schema (name — type — labels — emitting tiers):
 ``device_wait_ms``      histogram —            gateway, continuous,
                                                decode (the readback)
 ``forwards_by_rows``    counter   ``rows``     gateway, continuous
+``backbone_passes``     counter   —            gateway, continuous (the
+                                               steps of
+                                               ``forwards_by_rows`` x
+                                               the sampler's
+                                               ``passes_per_step``)
 ======================= ========= ============ =========================
 
 Module map:

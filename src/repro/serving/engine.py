@@ -118,6 +118,11 @@ class FlowSampler:
     def budgets(self) -> tuple[int, ...]:
         return (self.solver.n,)
 
+    @property
+    def passes_per_step(self) -> int:
+        """Backbone passes per NFE step (``models.model.passes_per_step``)."""
+        return M.passes_per_step(self.cfg, self.cfg_scale)
+
     def resolve_budget(self, m: int, strict: bool = False) -> int:
         """One served budget: exact match or (with ``strict``) rejection."""
         if m != self.solver.n and strict:
@@ -185,6 +190,11 @@ class AnytimeFlowSampler:
     def _field(self, batch: dict):
         return M.velocity_field(self.params, self.cfg, self.sched, batch,
                                 cfg_scale=self.cfg_scale)
+
+    @property
+    def passes_per_step(self) -> int:
+        """Backbone passes per NFE step (``models.model.passes_per_step``)."""
+        return M.passes_per_step(self.cfg, self.cfg_scale)
 
     def resolve_budget(self, m: int, strict: bool = False) -> int:
         """Route a requested NFE to a served budget (nearest; ties cheaper)."""

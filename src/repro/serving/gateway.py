@@ -699,12 +699,19 @@ class GatewayBase:
         ``steps`` NFE steps dispatched, each needed by ``rows`` real rows,
         under the labelled ``forwards_by_rows`` counter. Padded rows never
         count, so sum(rows x steps) over the labels is the steps the
-        requests themselves need."""
+        requests themselves need. ``backbone_passes`` adds the backbone
+        passes those steps ran: ``steps`` times the sampler's
+        ``passes_per_step`` (two where guidance runs its branches apart).
+        Only the flow gateways, which hold a ``sampler``, call this."""
         if rows > 0 and steps > 0:
             self.metrics.counter(
                 "forwards_by_rows",
                 "NFE steps dispatched, by the real rows that need them",
                 labels={"rows": str(rows)}).inc(steps)
+            self.metrics.counter(
+                "backbone_passes",
+                "backbone passes of the NFE steps dispatched").inc(
+                    steps * self.sampler.passes_per_step)
 
     def _note_tier(self, tier_shape: tuple, real: int, padded: int) -> None:
         """Per-tier occupancy accounting (caller holds ``_stats_lock``):

@@ -59,6 +59,8 @@ class FakeClock:
 class ToyAnytimeSampler:
     """Budget+carry-protocol sampler over the analytic toy field."""
 
+    passes_per_step = 1     # one unguided field evaluation per NFE step
+
     def __init__(self, budgets: Sequence[int] = (4, 8, 16), seed: int = 0,
                  jitter: float = 0.1, jit: bool = True):
         self.budgets = tuple(sorted(budgets))

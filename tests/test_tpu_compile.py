@@ -188,6 +188,35 @@ def _weight_relayouts(hlo: str, layers) -> tuple[list[str], list[str]]:
     return copies, slices
 
 
+def _flow_program(one_chip, program):
+    """The compiled flow program ``program`` names at yi-6b widths: a
+    flush ``jit__sample`` of budget 4 or (``leg...``) the trajectory leg
+    ``jit__extend`` 0..4, 4..8 when ``-guided-`` (over budgets 4/8/16),
+    over B x S latents from its last part ``<B>x<S>``."""
+    cfg = _yi6b_depth2()
+    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    guided = "-guided-" in program
+    budgets = (4, 8, 16) if guided else (4,)
+    sampler = AnytimeFlowSampler(params=None, cfg=cfg,
+                                 sched=get_scheduler("fm_ot"),
+                                 anytime=init_anytime(None, budgets),
+                                 budgets=budgets,
+                                 cfg_scale=1.5 if guided else 0.0)
+    B, S = map(int, program.rsplit("-", 1)[1].split("x"))
+    L = cfg.latent_dim
+    args = (_specs(one_chip, params),
+            {"tokens": _spec(one_chip, (B, S), jnp.int32)},
+            _spec(one_chip, (B, S, L)))
+    if program.startswith("leg"):
+        fn = sampler._leg(4, 8) if guided else sampler._leg(0, 4)
+        args += (_spec(one_chip, (max(budgets), B, S, L)),
+                 _spec(one_chip, (B, S, L)))
+    else:
+        fn = sampler._at_budget(4)
+    return fn.lower(*args).compile(), params
+
+
 @pytest.mark.parametrize("program", ["flush-8x32", "flush-guided-16x64",
                                      "leg-0-4-8x32"])
 def test_flow_program_reads_stacked_weights_in_place(one_chip, program):
@@ -195,32 +224,34 @@ def test_flow_program_reads_stacked_weights_in_place(one_chip, program):
     stacked layer weights as stored: no per-call relayout of a whole stack
     and no per-layer weight slice in the layer scan (the attention
     projections pin their outputs, ``models.attention._project``)."""
-    cfg = _yi6b_depth2()
-    params = jax.eval_shape(lambda k: M.init_params(k, cfg),
-                            jax.random.PRNGKey(0))
-    guided = program == "flush-guided-16x64"
-    budgets = (4, 8, 16) if guided else (4,)
-    sampler = AnytimeFlowSampler(params=None, cfg=cfg,
-                                 sched=get_scheduler("fm_ot"),
-                                 anytime=init_anytime(None, budgets),
-                                 budgets=budgets,
-                                 cfg_scale=1.5 if guided else 0.0)
-    B, S = (16, 64) if guided else (8, 32)
-    L = cfg.latent_dim
-    args = (_specs(one_chip, params),
-            {"tokens": _spec(one_chip, (B, S), jnp.int32)},
-            _spec(one_chip, (B, S, L)))
-    if program.startswith("leg"):
-        fn = sampler._leg(0, 4)
-        args += (_spec(one_chip, (max(budgets), B, S, L)),
-                 _spec(one_chip, (B, S, L)))
-    else:
-        fn = sampler._at_budget(4)
-    compiled = fn.lower(*args).compile()
+    compiled, params = _flow_program(one_chip, program)
     copies, slices = _weight_relayouts(compiled.as_text(), params["layers"])
     assert not copies, copies
     assert not slices, slices
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
+
+
+def _layer_scans(hlo: str, d_model: int) -> list[int]:
+    """Rows of the hidden state each layer scan of ``hlo`` carries: the
+    ``while`` loops whose state opens with the counter and a
+    (rows, positions, d_model) bf16 hidden state."""
+    pat = re.compile(r"\s*%\S+ = \(s32\[\]\S*, bf16\[(\d+),\d+,"
+                     + str(d_model) + r"\]\S* .*\bwhile\(")
+    return [int(m.group(1)) for m in map(pat.match, hlo.splitlines()) if m]
+
+
+@pytest.mark.parametrize("program,scans", [
+    ("flush-8x32", [8]),                # unguided: one pass over B rows
+    ("flush-guided-16x64", [32]),       # guided: one pass over 2B rows
+    ("leg-guided-16x64", [32] * 4)])    # leg 4..8: one pass a step
+def test_guided_step_is_one_layer_scan(one_chip, program, scans):
+    """A guided NFE step of the dense backbone is one layer scan over the
+    conditional and unconditional rows stacked on the batch axis, one
+    read of the layer weights where it took two scans of B rows each;
+    the unguided flush keeps its one scan over B rows."""
+    compiled, _ = _flow_program(one_chip, program)
+    assert _layer_scans(compiled.as_text(),
+                        _yi6b_depth2().d_model) == scans
 
 
 def test_pinned_prefill_no_worse_than_unpinned(one_chip, monkeypatch):
